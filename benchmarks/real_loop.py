@@ -16,6 +16,11 @@ Assertions (the acceptance gate):
 Wall-clock rows are informational (CPU psum timing is too noisy to gate).
 Runs in a subprocess so ``XLA_FLAGS`` lands before jax imports and the
 parent process keeps its single device.
+
+This design is a CPU rehearsal only.  The parent (``benchmarks/run.py``)
+has already imported JAX, and a chip belongs to one process: on a TPU the
+parent holds it and this child cannot reach it.  A chip version runs the
+loop in one process, as ``chip_smoke.py`` does.
 """
 
 from __future__ import annotations
@@ -36,7 +41,7 @@ from repro.configs.base import ShapeConfig
 from repro.core import bucketer, planner as planner_mod, profiler
 from repro.core.simulator import simulate
 from repro.data.pipeline import DataPipeline
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import recorder
 from repro.train import replan
@@ -64,7 +69,7 @@ t_f, tb_table = profiler.measure_loss_profile(
     lambda p, b: model.loss(p, b), (params, batch), metas, n_iters=2)
 
 # 2. PLAN from the measured costs; wfbp is the baseline partition.
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     _, _, art = build_train_step(model, run_cfg, mesh, strategy="wfbp",
                                  tb_table=tb_table, comm_model=mdl)
 specs = art.specs
@@ -79,7 +84,7 @@ assert pred_dp.t_iter <= pred_wfbp.t_iter + 1e-12, (
 # 3. EXECUTE + REFIT + REPLAN: live controller seeded with wfbp.
 rec = recorder.FlightRecorder()
 steps = 6
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     ctl, init_fn, cart = replan.closed_loop(
         model, run_cfg, mesh, strategy="wfbp", tb_table=tb_table,
         comm_model=mdl, t_f=t_f, recorder=rec,

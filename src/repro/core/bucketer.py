@@ -76,18 +76,18 @@ def tensor_specs(tree, t_b_fn: Callable[[LeafMeta], float]) -> list[TensorSpec]:
 #
 # Two buffer layouts share one contract:
 #   * plain (``use_kernel=False``): leaves concatenated back to back;
-#   * slot-aligned (``use_kernel=True``): each leaf occupies a TILE-aligned
-#     slot (zero-padded tail), the layout the bucket_pack Pallas kernel
-#     emits.  Pack and unpack must agree on ``use_kernel`` — the aligned
-#     total is ``packed_elems(metas, aligned=True)``.
+#   * slot-aligned (``use_kernel=True``): the bucket_pack kernel's slot
+#     layout (``kernels/bucket_pack/kernel.py``).  Pack and unpack must
+#     agree on ``use_kernel`` — the aligned total is
+#     ``packed_elems(metas, aligned=True)``.
 # ---------------------------------------------------------------------------
 
 def slot_elems(size: int, aligned: bool = False) -> int:
     """Elements a leaf of ``size`` occupies in the packed buffer."""
     if not aligned:
         return size
-    from repro.kernels.bucket_pack.kernel import TILE
-    return size + ((-size) % TILE)
+    from repro.kernels.bucket_pack.kernel import slot_elems as kernel_slot
+    return kernel_slot(size)
 
 
 def packed_elems(metas: Sequence[LeafMeta], aligned: bool = False) -> int:

@@ -22,6 +22,7 @@ which is the TPU-native realization of the paper's C++ comm thread.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Sequence
 
 import jax
@@ -33,27 +34,9 @@ from repro.core.planner import MergePlan
 AxisNames = str | Sequence[str]
 
 
-def axis_size(name: str) -> int:
-    """Static mesh-axis size inside a collective context, on any JAX.
-
-    New JAX has ``jax.lax.axis_size``; on old JAX ``psum(1, name)`` of a
-    concrete value constant-folds to the same static int.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
-
-
 def replicated_shard(buf: jax.Array, axis_name: str) -> jax.Array:
-    """This member's tile of a dim-0-even value replicated over ``axis_name``.
-
-    Only reached on new JAX: the sole caller is the ZeRO-1 step, which
-    ``build_train_step`` degrades to the replicated optimizer on old JAX
-    (its merged all-gather cannot compile inside an old partial-auto
-    shard_map anyway).
-    """
-    n = axis_size(axis_name)
+    """This member's tile of a dim-0-even value replicated over ``axis_name``."""
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     sz = buf.shape[0] // n
     return jax.lax.dynamic_slice_in_dim(buf, idx * sz, sz)
@@ -64,7 +47,7 @@ def _mean_scale(axis_names: AxisNames) -> Callable[[jax.Array], jax.Array]:
         n = 1
         names = (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
         for a in names:
-            n *= axis_size(a)
+            n *= jax.lax.axis_size(a)
         return x / n
     return scale
 
@@ -208,8 +191,8 @@ def hierarchical_allreduce(grads, plan: MergePlan, *, intra_axis: str = "data",
 
     def collective(buf):
         buf, restore = _wire_cast(buf, wire_dtype)
-        n = axis_size(intra_axis)
-        pad = (-buf.shape[0]) % n
+        n = jax.lax.axis_size(intra_axis)
+        pad = padded_elems(buf.shape[0], n) - buf.shape[0]
         if pad:
             buf = jnp.pad(buf, (0, pad))
         shard = safe_psum_scatter(buf, intra_axis, scatter_dimension=0,
@@ -229,9 +212,15 @@ def hierarchical_allreduce(grads, plan: MergePlan, *, intra_axis: str = "data",
 # ZeRO-1: merged reduce-scatter of grads + merged all-gather of params.
 # ---------------------------------------------------------------------------
 
-def bucket_shard_size(nelems: int, n: int) -> int:
-    """Padded per-shard element count for a tiled collective over n shards."""
-    return (nelems + n - 1) // n
+def padded_elems(nelems: int, n: int) -> int:
+    """Length of a bucket buffer padded for a tiled collective over ``n``
+    shards: every shard is a whole number of kernel TILEs, which is a
+    whole number of XLA:TPU's 1-D tiles in any dtype.  A shard that ends
+    inside a tile sends the TPU compiler down a path whose time and host
+    memory grow with the buffer."""
+    from repro.kernels.bucket_pack.kernel import TILE
+    q = n * TILE
+    return -(-nelems // q) * q
 
 
 def bucketed_reduce_scatter(grads, plan: MergePlan, axis_name: str,
@@ -250,14 +239,14 @@ def bucketed_reduce_scatter(grads, plan: MergePlan, axis_name: str,
     metas = bucketer.leaf_metadata(grads)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     by_path = {bucketer._path_str(p): v for p, v in flat}
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     shards, bucket_metas = [], []
     for bucket in plan.buckets:
         bmetas = [metas[i] for i in bucket]
         buf = bucketer.pack([by_path[m.path] for m in bmetas],
                             use_kernel=use_kernel)
         buf, restore = _wire_cast(buf, wire_dtype)
-        pad = (-buf.shape[0]) % n
+        pad = padded_elems(buf.shape[0], n) - buf.shape[0]
         if pad:
             buf = jnp.pad(buf, (0, pad))
         shard = safe_psum_scatter(buf, axis_name, scatter_dimension=0,
@@ -274,7 +263,9 @@ def bucketed_allgather(shards: Sequence[jax.Array],
                        bucket_metas: Sequence[Sequence[bucketer.LeafMeta]],
                        treedef_like, axis_name: str,
                        *, use_kernel: bool = False):
-    """Gather updated parameter shards back into the full pytree."""
+    """Gather updated parameter shards back into the full pytree, each
+    leaf in the dtype of its ``treedef_like`` counterpart (the metas may
+    describe float32 gradients of bfloat16 parameters)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(treedef_like)
     paths = [bucketer._path_str(p) for p, _ in flat]
     fwd_index = {p: i for i, p in enumerate(paths)}
@@ -283,6 +274,8 @@ def bucketed_allgather(shards: Sequence[jax.Array],
         full = jax.lax.all_gather(shard, axis_name, axis=0, tiled=True)
         total = bucketer.packed_elems(bmetas, aligned=use_kernel)
         full = full[:total]
+        bmetas = [dataclasses.replace(m, dtype=flat[fwd_index[m.path]][1].dtype)
+                  for m in bmetas]
         for m, arr in zip(bmetas, bucketer.unpack(full, bmetas,
                                                   use_kernel=use_kernel)):
             new_leaves[fwd_index[m.path]] = arr

@@ -1,13 +1,8 @@
 """jit'd wrappers for merged-gradient pack/unpack.
 
-Layout contract: both :func:`pack` and :func:`unpack` speak the TILE-aligned
-slot layout of ``kernel.py`` (each leaf zero-padded to a TILE multiple), and
-so does the pure-jnp fallback — the layouts are bit-identical, so callers
-(``core.bucketer``) never see which path executed.
-
-``interpret=None`` (default) auto-selects Pallas interpret mode on the CPU
-backend; where the kernel cannot lower at all (probed once per mode) the
-fallback builds the same buffer with pad+concatenate.
+Both speak the slot layout defined in ``kernel.py``.  ``interpret=None``
+(default) selects Pallas interpret mode on the CPU backend and the compiled
+kernel everywhere else; a kernel that cannot compile raises.
 """
 
 from __future__ import annotations
@@ -25,27 +20,6 @@ def _auto_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-_KERNEL_OK: dict[bool, bool] = {}
-
-
-def kernel_usable(interpret: bool) -> bool:
-    """Can the Pallas kernel lower in this mode on this backend?  Probed
-    once with a tiny compile; a failure selects the jnp fallback (same
-    slot-aligned layout) for the life of the process."""
-    ok = _KERNEL_OK.get(interpret)
-    if ok is None:
-        try:
-            x = jnp.zeros((K.TILE,), jnp.float32)
-            jax.block_until_ready(jax.jit(
-                lambda v: K.pack_kernel([v], jnp.float32,
-                                        interpret=interpret))(x))
-            ok = True
-        except Exception:  # noqa: BLE001 — any lowering failure means "no"
-            ok = False
-        _KERNEL_OK[interpret] = ok
-    return ok
-
-
 def _result_dtype(leaves, dtype):
     if dtype is not None:
         return jnp.dtype(dtype)
@@ -54,18 +28,14 @@ def _result_dtype(leaves, dtype):
 
 
 def pack(leaves, dtype=None, interpret: bool | None = None) -> jax.Array:
-    """Pack arbitrary-shaped leaves into one TILE-aligned flat buffer."""
+    """Pack arbitrary-shaped leaves into one slot-layout flat buffer."""
     dtype = _result_dtype(leaves, dtype)
     if interpret is None:
         interpret = _auto_interpret()
     flats = [pad_flat(l) for l in leaves]
-    if not kernel_usable(interpret):
-        casted = [f.astype(dtype) for f in flats]
-        return jnp.concatenate(casted) if len(casted) > 1 else casted[0]
-    pieces = []
-    for i in range(0, len(flats), MAX_SRCS_PER_CALL):
-        group = flats[i:i + MAX_SRCS_PER_CALL]
-        pieces.append(K.pack_kernel(group, dtype, interpret=interpret))
+    pieces = [K.pack_kernel(flats[i:i + MAX_SRCS_PER_CALL], dtype,
+                            interpret=interpret)
+              for i in range(0, len(flats), MAX_SRCS_PER_CALL)]
     return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
 
 
@@ -73,17 +43,14 @@ def unpack(buf: jax.Array, shapes, dtypes, interpret: bool | None = None):
     """Inverse of :func:`pack` (slot offsets recomputed from shapes)."""
     if interpret is None:
         interpret = _auto_interpret()
-    if not kernel_usable(interpret):
-        from repro.kernels.bucket_pack.ref import unpack_ref
-        return unpack_ref(buf, shapes, dtypes)
     out, off = [], 0
     for shape, dt in zip(shapes, dtypes):
         size = 1
         for d in shape:
             size *= d
-        padded = size + ((-size) % K.TILE)
-        piece = K.unpack_one_kernel(buf, off, padded, buf.dtype,
+        slot = K.slot_elems(size)
+        piece = K.unpack_one_kernel(buf, off, slot, buf.dtype,
                                     interpret=interpret)
         out.append(piece[:size].reshape(shape).astype(dt))
-        off += padded
+        off += slot
     return out

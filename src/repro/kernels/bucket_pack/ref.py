@@ -1,16 +1,16 @@
-"""Pure-jnp oracle for bucket pack/unpack (TILE-aligned concatenate)."""
+"""Pure-jnp oracle for bucket pack/unpack (the slot layout of kernel.py)."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bucket_pack.kernel import TILE
+from repro.kernels.bucket_pack.kernel import slot_elems
 
 
 def pad_flat(x: jax.Array) -> jax.Array:
     flat = x.reshape(-1)
-    pad = (-flat.shape[0]) % TILE
+    pad = slot_elems(flat.shape[0]) - flat.shape[0]
     return jnp.pad(flat, (0, pad)) if pad else flat
 
 
@@ -26,7 +26,6 @@ def unpack_ref(buf: jax.Array, shapes, dtypes):
         size = 1
         for d in shape:
             size *= d
-        padded = size + ((-size) % TILE)
         out.append(buf[off:off + size].reshape(shape).astype(dt))
-        off += padded
+        off += slot_elems(size)
     return out

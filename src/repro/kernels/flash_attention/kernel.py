@@ -21,18 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _compiler_params(**kwargs):
-    """TPU compiler params across Pallas versions (CompilerParams on new
-    JAX, TPUCompilerParams on 0.4.x)."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise NotImplementedError(
-            "this Pallas version exposes neither pltpu.CompilerParams nor "
-            "pltpu.TPUCompilerParams")
-    return cls(**kwargs)
-
 NEG_INF = -1e30
 
 
@@ -112,7 +100,7 @@ def flash_attention_kernel(q, k, v, *, scale: float, causal: bool,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

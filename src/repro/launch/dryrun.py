@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SHAPES
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import registry, sharding as shd
 from repro.models.transformer import LM
 from repro.serve.engine import build_serve_step
@@ -118,7 +118,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
            "run_overrides": run_overrides or {}}
     t0 = time.time()
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             if kind == "train":
                 step_fn, init_fn, art = step_mod.build_train_step(
                     model, run, mesh, strategy=strategy)
@@ -178,8 +178,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
             rec["compile_s"] = round(time.time() - t1, 2)
             rec["memory"] = _mem_dict(compiled)
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, list):   # old JAX: one dict per partition
-                ca = ca[0] if ca else {}
             rec["xla_cost"] = {"flops": float(ca.get("flops", 0.0)),
                                "bytes": float(ca.get("bytes accessed", 0.0))}
             txt = compiled.as_text()

@@ -1,55 +1,25 @@
-"""Production mesh construction + JAX version compatibility shims.
+"""Production mesh construction.
 
 Never touches jax device state at import time — everything is a function.
 Mesh shapes: single-pod (16, 16) = 256 chips ("data", "model"); multi-pod
 (2, 16, 16) = 512 chips ("pod", "data", "model").  ``pod`` is the DCN-level
 data-parallel axis (high startup cost — where gradient merging pays most).
 
-Compatibility: new JAX (>= 0.5) exposes ``jax.sharding.AxisType`` and
-``jax.set_mesh``; old JAX (0.4.x) has neither — ``jax.make_mesh`` takes no
-``axis_types`` and the ambient mesh is set with the ``with mesh:`` resource
-env.  :func:`make_mesh` and :func:`use_mesh` paper over the difference so
-every call site (and the tests) runs on both.
+Every axis is built Auto: the train step makes its data-parallel axes
+Manual inside its ``shard_map`` and leaves the rest to GSPMD.  Install a
+mesh as the ambient one with ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-# None on old JAX (< 0.5); the AxisType enum on new JAX.
-AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` on any JAX: request Auto axis types when supported.
-
-    New JAX wants explicit ``axis_types`` for GSPMD-auto partitioning; old
-    JAX predates axis types entirely (everything behaves as Auto).
-    """
-    if AXIS_TYPE is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, devices=devices,
-                axis_types=(AXIS_TYPE.Auto,) * len(axes))
-        except TypeError:
-            pass  # jax.make_mesh without the axis_types kwarg
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` on new JAX, ``with mesh:``
-    (the pjit resource env) on old JAX.  Either way bare ``PartitionSpec``
-    sharding constraints inside resolve against ``mesh``."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """``jax.make_mesh`` with every axis Auto (GSPMD partitioning)."""
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -58,13 +28,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh_for(devices: int, model_parallel: int = 0):
-    """Best-effort mesh for an arbitrary device count (tests / CPU runs)."""
-    if model_parallel <= 0:
-        model_parallel = 1
-        for cand in (16, 8, 4, 2):
-            if devices % cand == 0 and devices // cand >= 1:
-                model_parallel = cand
-                break
-    data = devices // model_parallel
-    return make_mesh((data, model_parallel), ("data", "model"))
+def make_mesh_for(devices: int, tp_enabled: bool):
+    """("data", "model") mesh over ``devices`` chips.
+
+    With tensor parallelism off every chip sits on ``data``, so none
+    duplicates another's work; with it on, the model axis takes two."""
+    model_parallel = 2 if (tp_enabled and devices % 2 == 0) else 1
+    return make_mesh((devices // model_parallel, model_parallel),
+                     ("data", "model"))

@@ -7,31 +7,26 @@ CPU-runnable example::
 """
 
 import argparse
-import os
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--devices", type=int, default=0)
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count={args.devices}")
-
     import time
     import jax
     import jax.numpy as jnp
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import registry
     from repro.serve.engine import ServeEngine
 
+    enable_compile_cache()
     bundle = registry.reduced_arch(args.arch) if args.reduced \
         else registry.get_arch(args.arch)
     model = bundle.model()

@@ -1,16 +1,16 @@
 """Training launcher: end-to-end driver over the real substrate.
 
-CPU-runnable example (reduced config)::
+Runs on every device JAX finds.  A reduced config for a quick run::
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
-        --reduced --steps 50 --devices 8
+        --reduced --steps 50
 
 Production launch is the same entry point with ``--shape train_4k`` and no
-``--reduced`` on a real 256/512-chip slice (the dry-run proves those
-configs compile; see launch/dryrun.py).
+``--reduced`` on a real slice (the dry-run proves those configs compile;
+see launch/dryrun.py).  ``chip_smoke.py`` at the repository root drives
+the same path at full width on one or four TPU chips.
 """
 
-import os
 import argparse
 
 
@@ -21,8 +21,6 @@ def main():
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--reduced", action="store_true",
                     help="reduced same-family config (CPU-scale)")
-    ap.add_argument("--devices", type=int, default=0,
-                    help="force N virtual host devices (CPU)")
     ap.add_argument("--global-batch", type=int, default=0)
     ap.add_argument("--seq-len", type=int, default=0)
     ap.add_argument("--strategy", default=None)
@@ -32,23 +30,19 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count={args.devices}")
-
     import dataclasses
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs.base import SHAPES, ShapeConfig
     from repro.data.pipeline import DataPipeline
-    from repro.launch.mesh import make_mesh_for, use_mesh
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh_for
     from repro.models import registry
     from repro.train import checkpoint, fault
     from repro.train.step import build_train_step
 
+    enable_compile_cache()
     bundle = registry.reduced_arch(args.arch) if args.reduced \
         else registry.get_arch(args.arch)
     shape = SHAPES[args.shape]
@@ -57,7 +51,7 @@ def main():
                             args.seq_len or shape.seq_len,
                             args.global_batch or shape.global_batch)
     n_dev = len(jax.devices())
-    mesh = make_mesh_for(n_dev, model_parallel=min(2, n_dev))
+    mesh = make_mesh_for(n_dev, bundle.parallel.tp_enabled)
     dims = dict(zip(mesh.axis_names, mesh.devices.shape))
     par = dataclasses.replace(bundle.parallel, dp_axes=("data",),
                               attn_chunk=min(bundle.parallel.attn_chunk,
@@ -66,17 +60,17 @@ def main():
                               shape=shape)
     model = bundle.model(par)
 
-    with use_mesh(mesh):
-        step_fn, init_fn, art = build_train_step(model, run, mesh,
-                                                 strategy=args.strategy)
+    step_fn, init_fn, art = build_train_step(model, run, mesh,
+                                             strategy=args.strategy)
+    with jax.set_mesh(mesh):
         print(f"arch={bundle.cfg.name} devices={n_dev} mesh={dims} "
               f"plan={art.plan.strategy}:{art.plan.num_buckets} buckets "
               f"over {art.plan.num_tensors} tensors")
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  art.state_pspecs,
                                  is_leaf=lambda x: isinstance(x, P))
-        state = jax.device_put(init_fn(jax.random.PRNGKey(run.seed)),
-                               shardings)
+        state = jax.jit(init_fn, out_shardings=shardings)(
+            jax.random.PRNGKey(run.seed))
         bsh = NamedSharding(mesh, art.batch_pspec)
         jstep = jax.jit(step_fn, donate_argnums=0)
 

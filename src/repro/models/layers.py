@@ -30,69 +30,19 @@ NEG_INF = -1e30
 # Sharding helper.
 # ---------------------------------------------------------------------------
 
-# None on old JAX (< 0.5), where axis types don't exist yet.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
 def _active_mesh_axis_names():
-    """Non-Manual axis names of the ambient mesh, or None when no mesh.
-
-    New JAX: the abstract mesh installed by ``jax.set_mesh`` / shard_map.
-    Old JAX (0.4.x): the ``with mesh:`` pjit resource env.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is None or mesh.empty:
-            return None
-        if _AXIS_TYPE is not None:
-            try:
-                return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
-                        if t != _AXIS_TYPE.Manual}
-            except Exception:
-                pass
-        return set(mesh.axis_names)
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    """Non-Manual axis names of the ambient mesh, or None when no mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
-    try:
-        from jax._src import core as _core
-        if _core.get_axis_env().axis_sizes:
-            # Inside a shard_map body: old XLA cannot mix sharding
-            # annotations with manual subgroups, so skip the hint.
-            return None
-    except Exception:
-        pass
-    return set(mesh.axis_names)
-
-
-def unroll_scans_here() -> bool:
-    """True when tracing inside a shard_map body on old JAX (< 0.5).
-
-    XLA of that era cannot partition ``lax.scan`` loops whose bodies sit in
-    a manual subgroup (fatal ``IsManualSubgroup`` check); callers unroll the
-    loop instead — identical math, longer compile.
-    """
-    if hasattr(jax, "shard_map"):
-        return False
-    try:
-        from jax._src import core as _core
-        return bool(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return False
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != jax.sharding.AxisType.Manual}
 
 
 def pshard(x: jax.Array, *spec) -> jax.Array:
     """with_sharding_constraint that degrades to identity off-mesh and
     ignores axes that are manual in the current (shard_map) context."""
-    try:
-        names = _active_mesh_axis_names()
-    except Exception:
-        return x
+    names = _active_mesh_axis_names()
     if names is None:
         return x
     clean = []

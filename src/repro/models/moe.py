@@ -76,26 +76,6 @@ def _positions_in_expert(flat_e: jax.Array, num_experts: int) -> jax.Array:
     return jnp.zeros((n,), jnp.int32).at[order].set(pos_sorted.astype(jnp.int32))
 
 
-def _topk_compat(probs: jax.Array, k: int):
-    """``lax.top_k`` with an iterative-argmax fallback.
-
-    The variadic sort behind top_k crashes the old (JAX 0.4.x) SPMD
-    partitioner inside a partial-auto shard_map; k is tiny (1-8) so k
-    argmax passes are an adequate substitute there.
-    """
-    if not layers.unroll_scans_here():
-        return jax.lax.top_k(probs, k)
-    p = probs
-    gates, idxs = [], []
-    for _ in range(k):
-        i = jnp.argmax(p, axis=-1)
-        gates.append(jnp.take_along_axis(p, i[:, None], axis=-1)[:, 0])
-        idxs.append(i)
-        p = jnp.where(jax.nn.one_hot(i, p.shape[-1], dtype=bool),
-                      -jnp.inf, p)
-    return jnp.stack(gates, axis=-1), jnp.stack(idxs, axis=-1)
-
-
 def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
               ep_axis: str = "", parallel=None
               ) -> tuple[jax.Array, jax.Array]:
@@ -123,7 +103,7 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
     # --- routing (fp32 for stability) ---
     logits = xf.astype(jnp.float32) @ params["router"]
     probs = jax.nn.softmax(logits, axis=-1)                   # [T, E]
-    gate, eidx = _topk_compat(probs, m.top_k)                 # [T, k]
+    gate, eidx = jax.lax.top_k(probs, m.top_k)                # [T, k]
     gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     # load-balance auxiliary loss (Switch-style)
     me = probs.mean(axis=0)
@@ -132,13 +112,7 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
     aux = m.num_experts * jnp.sum(me * ce)
 
     # --- capacity dispatch ---
-    from repro.core.comm import axis_size
-    # Old JAX inside shard_map: the EP all_to_all trips the old SPMD
-    # partitioner (like lax.scan — see layers.unroll_scans_here), so fall
-    # back to computing every expert locally; the step function mirrors
-    # this by treating expert grads as replicated.
-    ep_ok = ep_axis and not layers.unroll_scans_here()
-    ep = axis_size(ep_axis) if ep_ok else 1
+    ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     cap = int(math.ceil(t * m.top_k / m.num_experts * cap_factor))
     cap = max(8, -(-cap // 8) * 8)
     if ep > 1:

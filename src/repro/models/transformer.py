@@ -265,21 +265,10 @@ class LM:
                                         and not alternating):
                 group_apply = jax.checkpoint(group_apply, static_argnums=())
 
-            unroll = st.scanned and layers.unroll_scans_here()
             if alternating:
                 # remat every 2nd repeat-group: halves recompute FLOPs for
                 # one group's worth of live internals (§Perf iteration)
                 rematted = jax.checkpoint(group_apply, static_argnums=())
-
-                if unroll:
-                    for r in range(st.repeats // 2):
-                        gp_a = jax.tree.map(lambda l, r=r: l[2 * r], sp)
-                        gp_b = jax.tree.map(lambda l, r=r: l[2 * r + 1], sp)
-                        x, a1, _ = rematted(gp_a, x)
-                        x, a2, _ = group_apply(gp_b, x)
-                        aux_total = aux_total + a1 + a2
-                    states.append(None)
-                    continue
 
                 def scan_body2(carry, gp2):
                     x, aux = carry
@@ -295,20 +284,6 @@ class LM:
                                                  sp2)
                 states.append(None)
             elif st.scanned:
-                if unroll:
-                    collected = []
-                    for r in range(st.repeats):
-                        gp = jax.tree.map(lambda l, r=r: l[r], sp)
-                        x, a, s = group_apply(gp, x)
-                        aux_total = aux_total + a
-                        collected.append(s)
-                    if collect_states and collected:
-                        states.append(jax.tree.map(
-                            lambda *ls: jnp.stack(ls), *collected))
-                    else:
-                        states.append(None)
-                    continue
-
                 def scan_body(carry, gp):
                     x, aux = carry
                     x, a, s = group_apply(gp, x)

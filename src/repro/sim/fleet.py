@@ -26,7 +26,7 @@ assignments) is evaluated by ONE jitted jax.numpy kernel:
   cross-iteration recurrence, LocalSGD rounds — and selects per case by
   ``FleetForm.kind``, so heterogeneous batches (a mixed-schedule fleet)
   still take one device call;
-* precision: everything runs under ``jax.experimental.enable_x64`` so
+* precision: everything runs under ``jax.enable_x64`` so
   the recurrence arithmetic is float64 like the numpy fast path; the
   scan recurrence itself is operation-for-operation the numpy one
   (agreement to well under 1e-9 — only sum *reductions* may
@@ -405,7 +405,10 @@ def evaluate_cases(cases: Sequence[FleetCase],
                     f"inconsistent seed counts across cases: "
                     f"{c.s_max.shape[0]} vs {S}")
     k_max = max((len(c.bucket_bytes) for c in cases), default=0)
-    k_pad = 1 << (max(k_max, 1) - 1).bit_length()
+    # at least 2: XLA inlines a one-step scan, and the inlined body rounds
+    # differently from the loop, so a lone one-bucket case would not equal
+    # the same case in a padded batch
+    k_pad = 1 << (max(k_max, 2) - 1).bit_length()
     c_pad = 1 << (C - 1).bit_length()
 
     bb = np.zeros((k_pad, c_pad), dtype=np.float64)
@@ -433,11 +436,11 @@ def evaluate_cases(cases: Sequence[FleetCase],
         kind[ci] = c.kind
         if c.s_max is not None:
             sm[ci] = c.s_max
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     kern = _get_kernel()
     _kernel_call("evaluate")
-    with enable_x64():
+    with jax.enable_x64(True):
         t_iter, span = kern(
             jnp.asarray(bb), jnp.asarray(ro), jnp.asarray(mk),
             jnp.asarray(scal["a"]), jnp.asarray(scal["b"]),
@@ -613,11 +616,11 @@ def plan_cases(cases: Sequence[PlanCase], *,
         ready[:n, k] = c.ready
         ab[0, k], ab[1, k] = c.a, c.b
     if backend == "fleet":
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
         kern = _get_plan_kernel()
         _kernel_call("plan")
-        with enable_x64():
+        with jax.enable_x64(True):
             _, win = kern(jnp.asarray(pre), jnp.asarray(ready),
                           jnp.asarray(ab[0]), jnp.asarray(ab[1]))
         win = np.asarray(win)

@@ -68,8 +68,6 @@ def measure_comm_model(mesh, dp_axes: Sequence[str],
     an identity; the fit then captures dispatch overhead only, which is
     still the correct effective model for that (degenerate) topology.
     """
-    from repro.train.step import _shard_map
-
     axes = tuple(a for a in dp_axes if a in mesh.axis_names)
     samples_n: list[float] = []
     samples_t: list[float] = []
@@ -77,9 +75,9 @@ def measure_comm_model(mesh, dp_axes: Sequence[str],
         n_elems = max(1, int(nbytes) // 4)
         x = jnp.zeros((n_elems,), jnp.float32)
         if axes:
-            body = _shard_map(lambda v: jax.lax.psum(v, axes), mesh,
-                              in_specs=(P(),), out_specs=P(),
-                              manual_axes=frozenset(axes))
+            body = jax.shard_map(lambda v: jax.lax.psum(v, axes), mesh=mesh,
+                                 in_specs=(P(),), out_specs=P(),
+                                 axis_names=set(axes), check_vma=False)
         else:
             def body(v):
                 return v + 0.0

@@ -14,7 +14,7 @@ Construction (``build_train_step``) happens once, outside jit:
   3. Emit the step: ``shard_map`` with the DP axes *manual* (bucketed psum
      / reduce-scatter collectives placed explicitly, per plan — the paper's
      contribution) and the TP axis *auto* (GSPMD handles head/ffn sharding
-     incl. non-divisible head counts).
+     incl. non-divisible head counts).  Without TP every axis is manual.
 
 ZeRO-1 (``parallel.zero == 1``): per-plan-bucket reduce-scatter of grads
 over ``data`` (after a pod psum), optimizer on this shard's slice of the
@@ -34,7 +34,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import RunConfig
@@ -47,36 +46,6 @@ from repro.optim.schedule import warmup_cosine
 from repro.train.train_state import TrainState
 
 EP_LEAF_RE = re.compile(r"w_(gate|up|down)_e")
-
-
-def _microbatch_scan(body, carry, xs, n_micro):
-    """lax.scan over microbatches, unrolled where scan cannot lower (old
-    JAX inside a shard_map manual subgroup — see layers.unroll_scans_here)."""
-    from repro.models import layers as _layers
-    if not _layers.unroll_scans_here():
-        return jax.lax.scan(body, carry, xs)
-    ys = []
-    for i in range(n_micro):
-        mb = jax.tree.map(lambda x, i=i: x[i], xs)
-        carry, y = body(carry, mb)
-        ys.append(y)
-    stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
-    return carry, stacked
-
-
-def _shard_map(body, mesh, in_specs, out_specs, manual_axes):
-    """shard_map across JAX versions: new JAX takes ``axis_names`` (the
-    manual set) and ``check_vma``; old JAX (0.4.x) lives in
-    jax.experimental and takes the complementary ``auto`` set and
-    ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_old
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return sm_old(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, auto=auto)
 
 
 @dataclasses.dataclass
@@ -125,7 +94,7 @@ def _merge_groups(template, rep, ep):
 def _axes_size(axes) -> int:
     n = 1
     for a in axes:
-        n *= comm.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -261,7 +230,7 @@ def init_state(model: LM, opt: Optimizer, run: RunConfig,
     for bucket in plan.buckets:
         total = bucketer.packed_elems([metas[i] for i in bucket],
                                       aligned=aligned)
-        padded = total + ((-total) % zero_n)
+        padded = comm.padded_elems(total, zero_n)
         opt_shards.append(opt.init_leaf(jnp.zeros((padded,), jnp.float32)))
     if ep_on:
         opt_shards.append(opt.init(ep_p))
@@ -319,26 +288,20 @@ def build_train_step(model: LM, run: RunConfig, mesh,
     mesh_shape = tuple(mesh.devices.shape)
     dims = dict(zip(mesh_axes, mesh_shape))
     dp_axes = tuple(a for a in par.dp_axes if a in mesh_axes)
-    manual = frozenset(dp_axes)
+    tp_axis = par.tp_axis if (par.tp_enabled and par.tp_axis in mesh_axes
+                              and par.tp_axis not in dp_axes) else ""
+    # The DP axes are manual: the plan's collectives are placed by hand.
+    # The TP axis stays Auto for GSPMD; without TP no axis is left for
+    # GSPMD, so every axis goes manual (a Pallas kernel such as bucket_pack
+    # compiles for the TPU only where no axis is Auto).
+    manual = frozenset(dp_axes) if tp_axis else frozenset(mesh_axes)
     ep_on = bool(par.ep_axis) and par.ep_axis in mesh_axes
-    if ep_on and dp_axes and not hasattr(jax, "shard_map"):
-        # Old JAX: moe_apply skips the EP all_to_all inside shard_map (see
-        # models/moe.py), computing every expert locally — so expert leaves
-        # must be treated as replicated here too.
-        ep_on = False
     zero_axis = "data" if "data" in dp_axes else (dp_axes[0] if dp_axes
                                                   else "")
     pod_axes = tuple(a for a in dp_axes if a != zero_axis)
     zero_n = _static_size(dims, (zero_axis,)) if zero_axis else 1
     # effective ZeRO mode: sharded-state modes need a real data axis
     eff_zero = par.zero if (zero_axis and dp_axes) else 0
-    if eff_zero == 1 and not hasattr(jax, "shard_map"):
-        # Old JAX (< 0.5): the merged all-gather of updated params trips the
-        # old SPMD partitioner inside a partial-auto shard_map.  ZeRO-1 is
-        # numerically identical to the replicated optimizer (see
-        # tests/test_train_integration.py::test_zero1_matches_zero0), so
-        # degrade to the replicated path rather than crash.
-        eff_zero = 0
 
     opt = make_optimizer(run.optimizer, weight_decay=run.weight_decay,
                          state_dtype=run.optimizer_state_dtype,
@@ -347,12 +310,10 @@ def build_train_step(model: LM, run: RunConfig, mesh,
     lr_fn = warmup_cosine(run.learning_rate, run.warmup_steps,
                           run.total_steps)
     # paper §5.3 contiguous-buffer execution through the bucket_pack Pallas
-    # kernel (jnp fallback where Pallas cannot lower, same slot layout)
+    # kernel (slot-aligned buffer layout)
     use_kernel = bool(par.pack_kernel)
 
     params_shape = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-    tp_axis = par.tp_axis if (par.tp_enabled and par.tp_axis in mesh_axes
-                              and par.tp_axis not in dp_axes) else ""
     pspecs = shd.param_pspecs(params_shape,
                               ep_axis=par.ep_axis if ep_on else "",
                               tp_axis=tp_axis,
@@ -375,26 +336,27 @@ def build_train_step(model: LM, run: RunConfig, mesh,
                 f"but the step has {len(specs)}")
         plan = plan_override
 
-    # static per-bucket weight-decay masks (packed ZeRO-1 path only); the
-    # kernel layout pads each leaf's slot with zeros — padding never decays
-    decay_masks = []
+    # static per-bucket weight-decay masks (packed ZeRO-1 path only), as
+    # (length, value) runs.  The step builds each mask from broadcasts: a
+    # bucket-sized constant (2 GB for qwen2-1.5b's single bucket) makes the
+    # TPU compile of a sharded step take tens of GB of host memory.  The
+    # kernel layout pads each leaf's slot with zeros — padding never decays.
+    decay_runs = []
     if eff_zero == 1:
         rep_shape, _ = _split_groups(params_shape, ep_on)
         rep_metas = bucketer.leaf_metadata(rep_shape)
-        decay_by_path = {}
-        for p, _l in jax.tree_util.tree_flatten_with_path(rep_shape)[0]:
-            k = _keystr(p)
-            decay_by_path[k] = 1.0 if opt.weight_decay_mask(k) else 0.0
         for bucket in plan.buckets:
-            parts = []
+            runs: list[list] = []
             for i in bucket:
-                slot = np.zeros(
-                    (bucketer.slot_elems(rep_metas[i].size,
-                                         aligned=use_kernel),), np.float32)
-                slot[:rep_metas[i].size] = decay_by_path[rep_metas[i].path]
-                parts.append(slot)
-            decay_masks.append(np.concatenate(parts) if parts else
-                               np.zeros((0,), np.float32))
+                m = rep_metas[i]
+                slot = bucketer.slot_elems(m.size, aligned=use_kernel)
+                for n, v in ((m.size, float(opt.weight_decay_mask(m.path))),
+                             (slot - m.size, 0.0)):
+                    if runs and runs[-1][1] == v:
+                        runs[-1][0] += n
+                    elif n:
+                        runs.append([n, v])
+            decay_runs.append(runs)
 
     dp_size = _static_size(dims, dp_axes)
     local_batch = max(run.shape.global_batch // max(dp_size, 1), 1)
@@ -424,8 +386,8 @@ def build_train_step(model: LM, run: RunConfig, mesh,
 
         zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
                              params)
-        (gacc, loss_sum), metrics = _microbatch_scan(
-            mb_body, (zeros, jnp.zeros((), jnp.float32)), resh, n_micro)
+        (gacc, loss_sum), metrics = jax.lax.scan(
+            mb_body, (zeros, jnp.zeros((), jnp.float32)), resh)
         grads = jax.tree.map(lambda g: g / n_micro, gacc)
         metrics = jax.tree.map(lambda m: m[-1], metrics)
         return loss_sum / n_micro, metrics, grads
@@ -502,8 +464,9 @@ def build_train_step(model: LM, run: RunConfig, mesh,
         for k, (bmetas, gshard) in enumerate(zip(bucket_metas, shards)):
             pbuf = bucketer.pack([by_path[m.path] for m in bmetas],
                                  use_kernel=use_kernel)
-            mask = jnp.asarray(decay_masks[k])
-            pad = (-pbuf.shape[0]) % n
+            mask = jnp.concatenate([jnp.full((n,), v, jnp.float32)
+                                    for n, v in decay_runs[k]])
+            pad = comm.padded_elems(pbuf.shape[0], n) - pbuf.shape[0]
             if pad:
                 pbuf = jnp.pad(pbuf, (0, pad))
                 mask = jnp.pad(mask, (0, pad))
@@ -557,8 +520,8 @@ def build_train_step(model: LM, run: RunConfig, mesh,
                 return (acc, loss_acc + l), m
             zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
                                  state.params)
-            (grads, loss_sum), metrics = _microbatch_scan(
-                mb_body, (zeros, jnp.zeros((), jnp.float32)), resh, n_micro)
+            (grads, loss_sum), metrics = jax.lax.scan(
+                mb_body, (zeros, jnp.zeros((), jnp.float32)), resh)
             grads = jax.tree.map(lambda g: g / n_micro, grads)
             metrics = jax.tree.map(lambda m: m[-1], metrics)
             loss = loss_sum / n_micro
@@ -630,11 +593,11 @@ def build_train_step(model: LM, run: RunConfig, mesh,
         manual_state = jax.tree.map(
             lambda s: shd.manual_only(s, manual), st_pspecs,
             is_leaf=lambda x: isinstance(x, P))
-        step_fn = _shard_map(
-            body, mesh,
+        step_fn = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(manual_state, batch_pspec),
             out_specs=(manual_state, P()),
-            manual_axes=manual)
+            axis_names=set(manual), check_vma=False)
     else:
         step_fn = body
 

@@ -81,6 +81,19 @@ def test_pack_unpack_kernel_layout_roundtrip():
         bucketer.unpack(buf, metas, use_kernel=False)
 
 
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("nelems", [1, 4096, 280173056])
+def test_padded_elems_gives_whole_tiles_per_shard(nelems, n):
+    """ZeRO-1 bucket buffers split into n shards of whole kernel TILEs
+    (qwen2-1.5b's single bucket, 280173056 elements at one layer, left a
+    shard ending inside a tile), padding by less than one TILE per shard."""
+    from repro.core import comm
+    from repro.kernels.bucket_pack.kernel import TILE
+    padded = comm.padded_elems(nelems, n)
+    assert padded % (n * TILE) == 0
+    assert 0 <= padded - nelems < n * TILE
+
+
 def test_slot_elems_and_packed_elems():
     from repro.kernels.bucket_pack.kernel import TILE
     assert bucketer.slot_elems(5) == 5

@@ -22,10 +22,7 @@ def test_matches_xla_on_loop_free():
     x = jax.ShapeDtypeStruct((64, d), jnp.float32)
     w = jax.ShapeDtypeStruct((d, d), jnp.float32)
     comp, ours = _flops(f, x, w, w)
-    ca = comp.cost_analysis()
-    if isinstance(ca, list):  # old JAX: one properties dict per partition
-        ca = ca[0] if ca else {}
-    xla = ca["flops"]
+    xla = comp.cost_analysis()["flops"]
     # dot flops dominate; ours counts only dots, XLA adds elementwise
     assert ours.dot_flops == pytest.approx(2 * 2 * 64 * d * d)
     assert abs(ours.dot_flops - xla) / xla < 0.01

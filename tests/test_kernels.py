@@ -93,21 +93,24 @@ def test_bucket_pack_mixed_dtype_default_promotes():
 
 
 def test_bucket_pack_fallback_layout_identical():
-    """The jnp fallback emits the same TILE-aligned buffer as the kernel,
-    so a probe failure mid-fleet cannot change numerics or layout."""
+    """Kernel pack and unpack agree bit for bit with the jnp oracle in
+    ref.py, on slots big enough for several row blocks and on offsets
+    that force the smallest block."""
+    from repro.kernels.bucket_pack.kernel import MAX_BLOCK_ROWS, TILE
+    shapes = [(33,), (4 * MAX_BLOCK_ROWS, 128), (3, TILE), (512,)]
     leaves = [jax.random.normal(jax.random.PRNGKey(i), s)
-              for i, s in enumerate([(33,), (128, 7), (512,)])]
-    packed = bp_ops.pack(leaves, interpret=True)
-    bp_ops._KERNEL_OK[True] = False     # force the fallback path
-    try:
-        fb = bp_ops.pack(leaves, interpret=True)
-        np.testing.assert_array_equal(np.asarray(packed), np.asarray(fb))
-        outs = bp_ops.unpack(packed, [l.shape for l in leaves],
-                             [l.dtype for l in leaves], interpret=True)
-        for o, l in zip(outs, leaves):
-            np.testing.assert_array_equal(np.asarray(o), np.asarray(l))
-    finally:
-        bp_ops._KERNEL_OK.pop(True, None)
+              for i, s in enumerate(shapes)]
+    packed = bp_ops.pack(leaves, jnp.bfloat16, interpret=True)
+    ref = bp_ref.pack_ref(leaves, jnp.bfloat16)
+    assert packed.dtype == ref.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(packed, np.float32),
+                                  np.asarray(ref, np.float32))
+    dtypes = [l.dtype for l in leaves]
+    outs = bp_ops.unpack(packed, shapes, dtypes, interpret=True)
+    refs = bp_ref.unpack_ref(ref, shapes, dtypes)
+    for o, r in zip(outs, refs):
+        assert o.dtype == r.dtype and o.shape == r.shape
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
 
 
 # ---------------------------------------------------------------------------

@@ -409,7 +409,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataPipeline
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import recorder, timeline
 from repro.sim import trace
@@ -428,7 +428,7 @@ run_cfg = dataclasses.replace(bundle.run_config("train_4k", par),
 model = bundle.model(par)
 mesh = make_mesh((4,), ("data",))
 rec = recorder.FlightRecorder()
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     step_fn, init_fn, art = build_train_step(model, run_cfg, mesh)
     sh = jax.tree.map(lambda s: NamedSharding(mesh, s), art.state_pspecs,
                       is_leaf=lambda x: isinstance(x, P))

@@ -119,7 +119,8 @@ event_records = st.builds(
                          times, max_size=3))
 
 
-@given(st.lists(st.one_of(iteration_records, event_records), max_size=20))
+@given(records=st.lists(st.one_of(iteration_records, event_records),
+                         max_size=20))
 @settings(max_examples=50, deadline=None)
 def test_recorder_round_trip_identity(records, tmp_path_factory):
     path = tmp_path_factory.mktemp("obs") / "rec.jsonl"

@@ -151,7 +151,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataPipeline
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.obs import recorder
 from repro.train import replan
@@ -174,7 +174,7 @@ assert mdl.a >= 0.0 and mdl.time(1 << 20) > 0.0
 
 def run(steps, use_replan):
     rec = recorder.FlightRecorder()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if use_replan:
             ctl, init_fn, art = replan.closed_loop(
                 model, run_cfg, mesh, strategy="wfbp", comm_model=mdl,
@@ -216,7 +216,6 @@ for a, b in zip(jax.tree.leaves(state_ref.params),
 # 5. KERNEL PARITY inside all-manual shard_map: the Pallas packed path ==
 #    the plain concatenate path, for allreduce and for RS+AG
 from repro.core import bucketer, comm, planner as planner_mod
-from repro.train.step import _shard_map
 tree = {"w": jnp.arange(4 * 600, dtype=jnp.float32).reshape(4, 600),
         "b": jnp.arange(40, dtype=jnp.float32) * 0.5}
 metas = bucketer.leaf_metadata(tree)
@@ -227,8 +226,9 @@ def make_ar(use_kernel):
     def body(t):
         return comm.bucketed_allreduce(t, plan, "data", mode="packed",
                                        use_kernel=use_kernel)
-    return jax.jit(_shard_map(body, mesh, in_specs=(P(),), out_specs=P(),
-                              manual_axes=frozenset({"data"})))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                 out_specs=P(), axis_names={"data"},
+                                 check_vma=False))
 
 plain = make_ar(False)(tree)
 kern = make_ar(True)(tree)
@@ -241,8 +241,9 @@ def make_rsag(use_kernel):
                                                   use_kernel=use_kernel)
         return comm.bucketed_allgather(shards, bm, t, "data",
                                        use_kernel=use_kernel)
-    return jax.jit(_shard_map(body, mesh, in_specs=(P(),), out_specs=P(),
-                              manual_axes=frozenset({"data"})))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                 out_specs=P(), axis_names={"data"},
+                                 check_vma=False))
 
 plain = make_rsag(False)(tree)
 kern = make_rsag(True)(tree)

@@ -93,6 +93,27 @@ def test_zero1_matches_zero0(hp):
     np.testing.assert_allclose(wA, wB, rtol=2e-3, atol=2e-3)
 
 
+def test_zero1_step_keeps_state_types():
+    """A ZeRO-1 step on a data mesh, accumulating float32 gradients over
+    microbatches, returns the state in the shapes and dtypes it took:
+    bfloat16 parameters stay bfloat16, so the jitted step never retraces."""
+    bundle = registry.reduced_arch("qwen2-1.5b")
+    par = dataclasses.replace(bundle.parallel, dp_axes=("data",), zero=1,
+                              ep_axis="", attn_chunk=32)
+    shape = ShapeConfig("tiny", "train", 32, 4)
+    run = dataclasses.replace(bundle.run_config("train_4k", par),
+                              shape=shape, microbatch=2)
+    mesh = make_mesh((1,), ("data",))
+    step_fn, init_fn, _ = build_train_step(bundle.model(par), run, mesh)
+    state = init_fn(jax.random.PRNGKey(0))
+    new_state, _ = jax.jit(step_fn)(
+        state, DataPipeline(bundle.cfg, shape, seed=0).batch_at(0))
+
+    def avals(tree):
+        return jax.tree.map(lambda a: (a.shape, jnp.dtype(a.dtype)), tree)
+    assert avals(new_state) == avals(state)
+
+
 def test_lr_schedule_respects_run_config():
     """warmup_steps / total_steps flow from RunConfig into the step's LR
     schedule (previously hardcoded to 100 / 10000)."""
