@@ -12,6 +12,11 @@ parameters as a pytree; ``backward_order`` derives a deterministic tensor
 ordering from the tree paths, and model configs may override it with an
 explicit ordering when the pytree layout does not match execution order
 (e.g. scan-stacked layers, handled by ``expand_stacked``).
+
+Profiling: every op that packs, reduces or unpacks bucket ``k`` runs under
+the named scope ``bucket_<k>`` (:func:`bucket_scope`), ``k`` being the
+bucket's index in ``plan.buckets``; the compiled program's op metadata then
+says which bucket a device op belongs to.
 """
 
 from __future__ import annotations
@@ -35,6 +40,11 @@ class LeafMeta:
     dtype: Any
     size: int           # elements
     nbytes: int
+
+
+def bucket_scope(k: int):
+    """The named scope of bucket ``k``'s ops."""
+    return jax.named_scope(f"bucket_{k}")
 
 
 def _path_str(path) -> str:
@@ -147,16 +157,19 @@ def apply_bucketed(tree, plan: MergePlan,
     # backward-order index -> forward flat index
     fwd_index = {path: i for i, path in enumerate(paths)}
     new_leaves: list[Any] = [None] * len(leaves)
-    for bucket in plan.buckets:
+    for k, bucket in enumerate(plan.buckets):
         bmetas = [metas[i] for i in bucket]
         arrs = [leaves[fwd_index[m.path]] for m in bmetas]
         orig_dtype = arrs[0].dtype
-        buf = pack(arrs, dtype=comm_dtype or orig_dtype, use_kernel=use_kernel)
-        buf = collective(buf)
-        wire_metas = [dataclasses.replace(mm, dtype=buf.dtype) for mm in bmetas]
-        for m, arr in zip(bmetas, unpack(buf, wire_metas,
-                                         use_kernel=use_kernel)):
-            new_leaves[fwd_index[m.path]] = arr.astype(m.dtype)
+        with bucket_scope(k):
+            buf = pack(arrs, dtype=comm_dtype or orig_dtype,
+                       use_kernel=use_kernel)
+            buf = collective(buf)
+            wire_metas = [dataclasses.replace(mm, dtype=buf.dtype)
+                          for mm in bmetas]
+            for m, arr in zip(bmetas, unpack(buf, wire_metas,
+                                             use_kernel=use_kernel)):
+                new_leaves[fwd_index[m.path]] = arr.astype(m.dtype)
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 

@@ -17,7 +17,10 @@ according to a :class:`MergePlan`:
 
 All functions are pure and jit-safe; XLA's latency-hiding scheduler overlaps
 the per-bucket collectives with any remaining compute they do not depend on,
-which is the TPU-native realization of the paper's C++ comm thread.
+which is the TPU-native realization of the paper's C++ comm thread.  Each
+bucket's pack, collective and unpack run under the named scope
+``bucket_<k>`` (``bucketer.bucket_scope``), so that a profile attributes
+device time to the buckets of the plan.
 """
 
 from __future__ import annotations
@@ -142,27 +145,29 @@ def bucketed_allreduce(grads, plan: MergePlan, axis_names: AxisNames,
     fwd_index = {p: i for i, p in enumerate(paths)}
     leaves = [v for _, v in flat]
     new_leaves = list(leaves)
-    for bucket in plan.buckets:
-        idxs = [fwd_index[metas[i].path] for i in bucket]
-        casted, restores = [], []
-        for i in idxs:
-            c, r = _wire_cast(leaves[i], wire_dtype)
-            casted.append(c)
-            restores.append(r)
-        by_dtype: dict = {}
-        for pos, c in enumerate(casted):
-            by_dtype.setdefault(jnp.dtype(c.dtype), []).append(pos)
-        for dt, poss in sorted(by_dtype.items(), key=lambda kv: str(kv[0])):
-            ops = [casted[p] for p in poss]
-            promote = _cpu_promotes(dt)
-            if promote:
-                ops = [o.astype(jnp.float32) for o in ops]
-            reduced = jax.lax.psum(tuple(ops), axis_names)
-            if promote:
-                reduced = tuple(r.astype(dt) for r in reduced)
-            for p, red in zip(poss, reduced):
-                out = restores[p](red)
-                new_leaves[idxs[p]] = scale(out) if mean else out
+    for k, bucket in enumerate(plan.buckets):
+        with bucketer.bucket_scope(k):
+            idxs = [fwd_index[metas[i].path] for i in bucket]
+            casted, restores = [], []
+            for i in idxs:
+                c, r = _wire_cast(leaves[i], wire_dtype)
+                casted.append(c)
+                restores.append(r)
+            by_dtype: dict = {}
+            for pos, c in enumerate(casted):
+                by_dtype.setdefault(jnp.dtype(c.dtype), []).append(pos)
+            for dt, poss in sorted(by_dtype.items(),
+                                   key=lambda kv: str(kv[0])):
+                ops = [casted[p] for p in poss]
+                promote = _cpu_promotes(dt)
+                if promote:
+                    ops = [o.astype(jnp.float32) for o in ops]
+                reduced = jax.lax.psum(tuple(ops), axis_names)
+                if promote:
+                    reduced = tuple(r.astype(dt) for r in reduced)
+                for p, red in zip(poss, reduced):
+                    out = restores[p](red)
+                    new_leaves[idxs[p]] = scale(out) if mean else out
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 
@@ -241,19 +246,20 @@ def bucketed_reduce_scatter(grads, plan: MergePlan, axis_name: str,
     by_path = {bucketer._path_str(p): v for p, v in flat}
     n = jax.lax.axis_size(axis_name)
     shards, bucket_metas = [], []
-    for bucket in plan.buckets:
+    for k, bucket in enumerate(plan.buckets):
         bmetas = [metas[i] for i in bucket]
-        buf = bucketer.pack([by_path[m.path] for m in bmetas],
-                            use_kernel=use_kernel)
-        buf, restore = _wire_cast(buf, wire_dtype)
-        pad = padded_elems(buf.shape[0], n) - buf.shape[0]
-        if pad:
-            buf = jnp.pad(buf, (0, pad))
-        shard = safe_psum_scatter(buf, axis_name, scatter_dimension=0,
-                                  tiled=True)
-        shard = restore(shard)
-        if mean:
-            shard = shard / n
+        with bucketer.bucket_scope(k):
+            buf = bucketer.pack([by_path[m.path] for m in bmetas],
+                                use_kernel=use_kernel)
+            buf, restore = _wire_cast(buf, wire_dtype)
+            pad = padded_elems(buf.shape[0], n) - buf.shape[0]
+            if pad:
+                buf = jnp.pad(buf, (0, pad))
+            shard = safe_psum_scatter(buf, axis_name, scatter_dimension=0,
+                                      tiled=True)
+            shard = restore(shard)
+            if mean:
+                shard = shard / n
         shards.append(shard)
         bucket_metas.append(bmetas)
     return shards, bucket_metas
@@ -270,15 +276,15 @@ def bucketed_allgather(shards: Sequence[jax.Array],
     paths = [bucketer._path_str(p) for p, _ in flat]
     fwd_index = {p: i for i, p in enumerate(paths)}
     new_leaves = [None] * len(flat)
-    for shard, bmetas in zip(shards, bucket_metas):
-        full = jax.lax.all_gather(shard, axis_name, axis=0, tiled=True)
-        total = bucketer.packed_elems(bmetas, aligned=use_kernel)
-        full = full[:total]
+    for k, (shard, bmetas) in enumerate(zip(shards, bucket_metas)):
         bmetas = [dataclasses.replace(m, dtype=flat[fwd_index[m.path]][1].dtype)
                   for m in bmetas]
-        for m, arr in zip(bmetas, bucketer.unpack(full, bmetas,
-                                                  use_kernel=use_kernel)):
-            new_leaves[fwd_index[m.path]] = arr
+        with bucketer.bucket_scope(k):
+            full = jax.lax.all_gather(shard, axis_name, axis=0, tiled=True)
+            full = full[:bucketer.packed_elems(bmetas, aligned=use_kernel)]
+            for m, arr in zip(bmetas, bucketer.unpack(full, bmetas,
+                                                      use_kernel=use_kernel)):
+                new_leaves[fwd_index[m.path]] = arr
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 

@@ -11,7 +11,9 @@ pure ``lax.scan`` form) so 32k-524k sequence dry-runs lower without
 materializing S×S score matrices; the Pallas kernel in
 ``repro/kernels/flash_attention`` implements the same schedule with explicit
 VMEM tiling for the TPU target and is validated against
-:func:`attention_ref`.
+:func:`attention_ref`.  Both run under the named scope ``attention``, so
+that a profile can tell the attention core's device time from the
+projections around it.
 """
 
 from __future__ import annotations
@@ -130,6 +132,7 @@ def _gqa_expand(q: jax.Array, num_kv: int) -> jax.Array:
     return q.reshape(b, s, num_kv, hq // num_kv, d)
 
 
+@jax.named_scope("attention")
 def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
                   q_offset=0, kv_len: Optional[jax.Array] = None,
                   scale: Optional[float] = None) -> jax.Array:
@@ -163,6 +166,7 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     return out.reshape(b, sq, hq, d).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               q_offset=0, kv_len: Optional[jax.Array] = None,
               chunk: int = 1024, scale: Optional[float] = None) -> jax.Array:

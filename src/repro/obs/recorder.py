@@ -58,9 +58,12 @@ class IterationRecord:
     """One training iteration, simulator- or real-run-sourced.
 
     ``source`` distinguishes provenance (``"sim"`` | ``"train"``), not
-    schema: both producers fill the same fields, with real runs leaving
-    the engine-only telemetry (worker frontiers, link accounting) empty
-    and flagging estimated bucket timings in ``args``.
+    schema: both producers fill the same fields.  A real run's record
+    holds the measured wall window only: no buckets (the host does not
+    see a collective's window; a device profile read through the step's
+    ``bucket_<k>`` named scopes does), ``backward_end`` equal to ``end``,
+    and the engine-only telemetry (worker frontiers, link accounting)
+    empty.
     """
 
     source: str

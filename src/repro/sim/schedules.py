@@ -889,9 +889,11 @@ class _DAGDriver:
         if not tasks:
             self._complete()
             return
-        for t in tasks:                 # deterministic: declaration order
-            if self._missing[t.name] == 0:
-                self._dispatch(t)
+        # deterministic: declaration order.  Taken before any dispatch: a
+        # zero-length link flow finishes inside its dispatch and readies
+        # its dependents, which it dispatches itself.
+        for t in [t for t in tasks if self._missing[t.name] == 0]:
+            self._dispatch(t)
 
     def _dispatch(self, t: DAGTask) -> None:
         if t.worker is None:
@@ -924,12 +926,14 @@ class _DAGDriver:
                 else:
                     self._busy[t.worker] = False
             self._done += 1
+            if self._done == len(self.schedule.tasks):
+                self._complete()
+            # a dependent may finish inside its dispatch (a zero-length
+            # link flow), and complete the graph there
             for dep in self._dependents.get(t.name, ()):
                 self._missing[dep.name] -= 1
                 if self._missing[dep.name] == 0:
                     self._dispatch(dep)
-            if self._done == len(self.schedule.tasks):
-                self._complete()
 
         if t.link is not None:
             run.sim.ensure_link(t.link)

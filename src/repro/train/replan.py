@@ -231,8 +231,8 @@ class ReplanController:
         # Observed non-overlapped communication: everything the wall
         # clock spent beyond forward + backward compute.  The stretch of
         # that bottleneck against its prediction is the refit signal —
-        # uniform rescaling of (a, b) when we cannot separate per-bucket
-        # durations (host-side records carry estimates, not measurements).
+        # uniform rescaling of (a, b), since the host-side records hold
+        # the step's wall window alone and no per-bucket durations.
         obs_t_c_no = max(observed - (self.t_f + pred.t_b_total), 0.0)
         if pred.t_c_no > _EPS:
             stretch = obs_t_c_no / pred.t_c_no

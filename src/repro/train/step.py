@@ -21,6 +21,16 @@ over ``data`` (after a pod psum), optimizer on this shard's slice of the
 packed bucket, merged all-gather of updated params — the same startup-cost
 amortization argument the paper makes for all-reduce, applied to RS+AG.
 
+Named scopes: the step's phases run under ``jax.named_scope`` so that a
+profile of the compiled step attributes each device op to one of them —
+``forward`` (opened inside the differentiated function, so that JAX names
+the backward pass ``transpose(jvp(forward))`` and the ops ``jax.checkpoint``
+recomputes ``.../rematted_computation``), ``accumulate`` (the microbatch
+sum), ``grad_sync`` (the gradient reduction and the parameter repack,
+``bucket_<k>`` per bucket of the plan, ``ep`` for the expert group) and
+``optimizer`` (global norm, clipping, update).  Scopes only add metadata
+to the compiled program.
+
 Note on pytrees: group splitting inserts ``None`` at excluded leaves; JAX
 treats ``None`` as an empty subtree, so the pruned trees flow through
 bucketer/comm/optim untouched.
@@ -365,10 +375,13 @@ def build_train_step(model: LM, run: RunConfig, mesh,
 
     # ------------------------------------------------------------------
 
-    def compute_grads(params, batch):
-        def loss_fn(p, mb):
-            return model.loss(p, mb)
+    def model_loss(params, mb):
+        with jax.named_scope("forward"):
+            return model.loss(params, mb)
 
+    def compute_grads(loss_fn, params, batch):
+        """Loss, metrics and gradients of ``loss_fn`` over the local batch,
+        accumulated over ``n_micro`` microbatches."""
         if n_micro == 1:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
@@ -380,17 +393,20 @@ def build_train_step(model: LM, run: RunConfig, mesh,
             acc, loss_acc = carry
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, mb)
-            acc = jax.tree.map(lambda a, g: a + g.astype(a.dtype), acc,
-                               grads)
-            return (acc, loss_acc + loss), metrics
+            with jax.named_scope("accumulate"):
+                acc = jax.tree.map(lambda a, g: a + g.astype(a.dtype), acc,
+                                   grads)
+                return (acc, loss_acc + loss), metrics
 
-        zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
-                             params)
+        with jax.named_scope("accumulate"):
+            zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
+                                 params)
         (gacc, loss_sum), metrics = jax.lax.scan(
             mb_body, (zeros, jnp.zeros((), jnp.float32)), resh)
-        grads = jax.tree.map(lambda g: g / n_micro, gacc)
-        metrics = jax.tree.map(lambda m: m[-1], metrics)
-        return loss_sum / n_micro, metrics, grads
+        with jax.named_scope("accumulate"):
+            grads = jax.tree.map(lambda g: g / n_micro, gacc)
+            metrics = jax.tree.map(lambda m: m[-1], metrics)
+            return loss_sum / n_micro, metrics, grads
 
     def reduce_replicated(rep_g):
         kwargs = dict(mean=True, wire_dtype=par.wire_dtype or None)
@@ -410,51 +426,66 @@ def build_train_step(model: LM, run: RunConfig, mesh,
         if ep_g is None:
             return None
         if pod_axes and ep_plan is not None:
-            return comm.bucketed_allreduce(ep_g, ep_plan, pod_axes,
-                                           mean=True)
+            with jax.named_scope("ep"):
+                return comm.bucketed_allreduce(ep_g, ep_plan, pod_axes,
+                                               mean=True)
         return ep_g
+
+    def finish(state, new_params, new_opt, metrics, loss, gnorm, lr):
+        """The next state, and the step's metrics averaged over the data
+        axes; in the ``optimizer`` scope, beside the update."""
+        with jax.named_scope("optimizer"):
+            metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)
+            if dp_axes:
+                metrics = jax.tree.map(lambda m: jax.lax.pmean(m, dp_axes),
+                                       metrics)
+            return TrainState(state.step + 1, new_params, new_opt), metrics
 
     # ------------------------------------------------------------------
 
     def step_zero0(state: TrainState, batch):
-        loss, metrics, grads = compute_grads(state.params, batch)
-        rep_g, ep_g = _split_groups(grads, ep_on)
-        rep_g = reduce_replicated(rep_g)
-        ep_g = reduce_ep(ep_g) if ep_on else None
-        grads = _merge_groups(grads, rep_g, ep_g)
-        sq = oclip.global_norm(rep_g) ** 2
-        if ep_on and zero_axis:
-            sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2, zero_axis)
-        gnorm = jnp.sqrt(sq)
-        grads, _ = oclip.clip_by_global_norm(grads, run.grad_clip, gnorm)
-        lr = lr_fn(state.step)
-        new_params, new_opt = opt.update(grads, state.params,
-                                         state.opt_state, state.step, lr)
-        metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)
-        if dp_axes:
-            metrics = jax.tree.map(lambda m: jax.lax.pmean(m, dp_axes),
-                                   metrics)
-        return TrainState(state.step + 1, new_params, new_opt), metrics
+        loss, metrics, grads = compute_grads(model_loss, state.params, batch)
+        with jax.named_scope("grad_sync"):
+            rep_g, ep_g = _split_groups(grads, ep_on)
+            rep_g = reduce_replicated(rep_g)
+            ep_g = reduce_ep(ep_g) if ep_on else None
+            grads = _merge_groups(grads, rep_g, ep_g)
+        with jax.named_scope("optimizer"):
+            sq = oclip.global_norm(rep_g) ** 2
+            if ep_on and zero_axis:
+                sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2,
+                                       zero_axis)
+            gnorm = jnp.sqrt(sq)
+            grads, _ = oclip.clip_by_global_norm(grads, run.grad_clip, gnorm)
+            lr = lr_fn(state.step)
+            new_params, new_opt = opt.update(grads, state.params,
+                                             state.opt_state, state.step, lr)
+        return finish(state, new_params, new_opt, metrics, loss, gnorm, lr)
 
     def step_zero1(state: TrainState, batch):
-        loss, metrics, grads = compute_grads(state.params, batch)
-        rep_g, ep_g = _split_groups(grads, ep_on)
-        if pod_axes:
-            npod = _static_size(dims, pod_axes)
-            rep_g = jax.tree.map(lambda g: g / npod,
-                                 comm.safe_psum(rep_g, pod_axes))
-        shards, bucket_metas = comm.bucketed_reduce_scatter(
-            rep_g, plan, zero_axis, mean=True,
-            wire_dtype=par.wire_dtype or None, use_kernel=use_kernel)
-        sq = sum(jnp.sum(jnp.square(s.astype(jnp.float32))) for s in shards)
-        sq = jax.lax.psum(sq, zero_axis)
-        ep_g = reduce_ep(ep_g) if ep_on else None
-        if ep_on:
-            sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2, zero_axis)
-        gnorm = jnp.sqrt(sq)
-        scale = (jnp.minimum(1.0, run.grad_clip / jnp.maximum(gnorm, 1e-12))
-                 if run.grad_clip > 0 else jnp.ones(()))
-        lr = lr_fn(state.step)
+        loss, metrics, grads = compute_grads(model_loss, state.params, batch)
+        with jax.named_scope("grad_sync"):
+            rep_g, ep_g = _split_groups(grads, ep_on)
+            if pod_axes:
+                npod = _static_size(dims, pod_axes)
+                rep_g = jax.tree.map(lambda g: g / npod,
+                                     comm.safe_psum(rep_g, pod_axes))
+            shards, bucket_metas = comm.bucketed_reduce_scatter(
+                rep_g, plan, zero_axis, mean=True,
+                wire_dtype=par.wire_dtype or None, use_kernel=use_kernel)
+            ep_g = reduce_ep(ep_g) if ep_on else None
+        with jax.named_scope("optimizer"):
+            sq = sum(jnp.sum(jnp.square(s.astype(jnp.float32)))
+                     for s in shards)
+            sq = jax.lax.psum(sq, zero_axis)
+            if ep_on:
+                sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2,
+                                       zero_axis)
+            gnorm = jnp.sqrt(sq)
+            scale = (jnp.minimum(1.0,
+                                 run.grad_clip / jnp.maximum(gnorm, 1e-12))
+                     if run.grad_clip > 0 else jnp.ones(()))
+            lr = lr_fn(state.step)
 
         n = _axes_size((zero_axis,))
         rep_p, ep_p = _split_groups(state.params, ep_on)
@@ -462,35 +493,39 @@ def build_train_step(model: LM, run: RunConfig, mesh,
         by_path = {_keystr(p): v for p, v in flatp}
         new_shards, new_opt = [], []
         for k, (bmetas, gshard) in enumerate(zip(bucket_metas, shards)):
-            pbuf = bucketer.pack([by_path[m.path] for m in bmetas],
-                                 use_kernel=use_kernel)
-            mask = jnp.concatenate([jnp.full((n,), v, jnp.float32)
-                                    for n, v in decay_runs[k]])
-            pad = comm.padded_elems(pbuf.shape[0], n) - pbuf.shape[0]
-            if pad:
-                pbuf = jnp.pad(pbuf, (0, pad))
-                mask = jnp.pad(mask, (0, pad))
-            pshard = comm.replicated_shard(pbuf, zero_axis)
-            mshard = comm.replicated_shard(mask, zero_axis)
-            g = gshard.astype(jnp.float32) * scale
-            new_p, new_s = opt.flat_update(g, pshard, state.opt_state[k],
-                                           state.step, lr, mshard)
+            # this shard's slice of bucket k's parameters, packed as its
+            # gradients were
+            with jax.named_scope("grad_sync"), bucketer.bucket_scope(k):
+                pbuf = bucketer.pack([by_path[m.path] for m in bmetas],
+                                     use_kernel=use_kernel)
+                pad = comm.padded_elems(pbuf.shape[0], n) - pbuf.shape[0]
+                if pad:
+                    pbuf = jnp.pad(pbuf, (0, pad))
+                pshard = comm.replicated_shard(pbuf, zero_axis)
+            with jax.named_scope("optimizer"):
+                mask = jnp.concatenate([jnp.full((n,), v, jnp.float32)
+                                        for n, v in decay_runs[k]])
+                if pad:
+                    mask = jnp.pad(mask, (0, pad))
+                mshard = comm.replicated_shard(mask, zero_axis)
+                g = gshard.astype(jnp.float32) * scale
+                new_p, new_s = opt.flat_update(g, pshard, state.opt_state[k],
+                                               state.step, lr, mshard)
             new_shards.append(new_p)
             new_opt.append(new_s)
-        new_rep = comm.bucketed_allgather(new_shards, bucket_metas, rep_p,
-                                          zero_axis, use_kernel=use_kernel)
+        with jax.named_scope("grad_sync"):
+            new_rep = comm.bucketed_allgather(new_shards, bucket_metas, rep_p,
+                                              zero_axis, use_kernel=use_kernel)
+        new_ep = None
         if ep_on:
-            ep_gc = jax.tree.map(lambda g: g * scale, ep_g)
-            new_ep, new_ep_opt = opt.update(ep_gc, ep_p,
-                                            state.opt_state[-1],
-                                            state.step, lr)
+            with jax.named_scope("optimizer"):
+                ep_gc = jax.tree.map(lambda g: g * scale, ep_g)
+                new_ep, new_ep_opt = opt.update(ep_gc, ep_p,
+                                                state.opt_state[-1],
+                                                state.step, lr)
             new_opt.append(new_ep_opt)
-        else:
-            new_ep = None
         new_params = _merge_groups(state.params, new_rep, new_ep)
-        metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)
-        metrics = jax.tree.map(lambda m: jax.lax.pmean(m, dp_axes), metrics)
-        return TrainState(state.step + 1, new_params, new_opt), metrics
+        return finish(state, new_params, new_opt, metrics, loss, gnorm, lr)
 
     # ------------------------------------------------------------------
     # ZeRO-3 / FSDP: params + optimizer fully sharded over `data`; the
@@ -498,33 +533,15 @@ def build_train_step(model: LM, run: RunConfig, mesh,
     # ------------------------------------------------------------------
 
     def step_zero3(state: TrainState, batch):
-        dp_n = _axes_size(dp_axes)
-
         def loss_of_sharded(sharded_params, mb):
-            full = gather_fsdp(sharded_params, fsdp_dims, zero_axis)
-            return model.loss(full, mb)
+            # the gather's transpose, the gradients' reduce-scatter, is
+            # named transpose(jvp(grad_sync))
+            with jax.named_scope("grad_sync"):
+                full = gather_fsdp(sharded_params, fsdp_dims, zero_axis)
+            return model_loss(full, mb)
 
-        if n_micro == 1:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_of_sharded, has_aux=True)(state.params, batch)
-        else:
-            resh = jax.tree.map(
-                lambda x: x.reshape((n_micro, micro) + x.shape[1:]), batch)
-
-            def mb_body(carry, mb):
-                acc, loss_acc = carry
-                (l, m), g = jax.value_and_grad(
-                    loss_of_sharded, has_aux=True)(state.params, mb)
-                acc = jax.tree.map(lambda a, gg: a + gg.astype(a.dtype),
-                                   acc, g)
-                return (acc, loss_acc + l), m
-            zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
-                                 state.params)
-            (grads, loss_sum), metrics = jax.lax.scan(
-                mb_body, (zeros, jnp.zeros((), jnp.float32)), resh)
-            grads = jax.tree.map(lambda g: g / n_micro, grads)
-            metrics = jax.tree.map(lambda m: m[-1], metrics)
-            loss = loss_sum / n_micro
+        loss, metrics, grads = compute_grads(loss_of_sharded, state.params,
+                                             batch)
 
         # fsdp leaves arrive as per-shard sums over `data` (gather
         # transpose); non-fsdp leaves are local and need the plan's
@@ -543,31 +560,32 @@ def build_train_step(model: LM, run: RunConfig, mesh,
             return (jax.tree_util.tree_unflatten(treedef, f_leaves),
                     jax.tree_util.tree_unflatten(treedef, r_leaves))
 
-        fsdp_g, rest_g = split3(grads)
-        rep_g, ep_g = _split_groups(rest_g, ep_on)
-        rep_g = reduce_replicated(rep_g)
-        ep_g = reduce_ep(ep_g) if ep_on else None
-        if pod_axes:
-            npod = _static_size(dims, pod_axes)
-            fsdp_g = jax.tree.map(lambda g: g / npod,
-                                  comm.safe_psum(fsdp_g, pod_axes))
-        fsdp_g = jax.tree.map(lambda g: g / _axes_size((zero_axis,)),
-                              fsdp_g)
-        grads = _merge_groups(grads, _merge_groups(rest_g, rep_g, ep_g),
-                              fsdp_g)
+        with jax.named_scope("grad_sync"):
+            fsdp_g, rest_g = split3(grads)
+            rep_g, ep_g = _split_groups(rest_g, ep_on)
+            rep_g = reduce_replicated(rep_g)
+            ep_g = reduce_ep(ep_g) if ep_on else None
+            if pod_axes:
+                npod = _static_size(dims, pod_axes)
+                fsdp_g = jax.tree.map(lambda g: g / npod,
+                                      comm.safe_psum(fsdp_g, pod_axes))
+            fsdp_g = jax.tree.map(lambda g: g / _axes_size((zero_axis,)),
+                                  fsdp_g)
+            grads = _merge_groups(grads, _merge_groups(rest_g, rep_g, ep_g),
+                                  fsdp_g)
 
-        sq = oclip.global_norm(rep_g) ** 2
-        sq = sq + jax.lax.psum(oclip.global_norm(fsdp_g) ** 2, zero_axis)
-        if ep_on:
-            sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2, zero_axis)
-        gnorm = jnp.sqrt(sq)
-        grads, _ = oclip.clip_by_global_norm(grads, run.grad_clip, gnorm)
-        lr = lr_fn(state.step)
-        new_params, new_opt = opt.update(grads, state.params,
-                                         state.opt_state, state.step, lr)
-        metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)
-        metrics = jax.tree.map(lambda m: jax.lax.pmean(m, dp_axes), metrics)
-        return TrainState(state.step + 1, new_params, new_opt), metrics
+        with jax.named_scope("optimizer"):
+            sq = oclip.global_norm(rep_g) ** 2
+            sq = sq + jax.lax.psum(oclip.global_norm(fsdp_g) ** 2, zero_axis)
+            if ep_on:
+                sq = sq + jax.lax.psum(oclip.global_norm(ep_g) ** 2,
+                                       zero_axis)
+            gnorm = jnp.sqrt(sq)
+            grads, _ = oclip.clip_by_global_norm(grads, run.grad_clip, gnorm)
+            lr = lr_fn(state.step)
+            new_params, new_opt = opt.update(grads, state.params,
+                                             state.opt_state, state.step, lr)
+        return finish(state, new_params, new_opt, metrics, loss, gnorm, lr)
 
     if eff_zero == 3:
         body = step_zero3
@@ -628,15 +646,16 @@ def instrument_step(step_fn, art: StepArtifacts, *, job: str = "train",
     Timing happens strictly OUTSIDE the jitted region — wall clock before
     dispatch and after ``jax.block_until_ready`` — so nothing lands on the
     device hot path (no Python callbacks inside jit, acceptance criterion
-    of the obs subsystem).  Per-bucket communication windows are not
-    host-observable, so each record carries the closed-form per-bucket
-    estimate (``core.simulator.simulate`` over the step's own plan, specs
-    and comm model — the same Eq. 7/8 replay the planner optimized
-    against) rescaled to the measured wall time and flagged
-    ``estimated_buckets`` in ``args``.  The result: a real multi-device
-    run produces :class:`repro.obs.recorder.IterationRecord`s in exactly
-    the simulator's schema, and both export into one Chrome trace
-    (``repro.obs.recorder.record_spans``).
+    of the obs subsystem).  Each record holds the measured wall window
+    (``start``, ``end``) in the simulator's record schema
+    (:class:`repro.obs.recorder.IterationRecord`), and both export into
+    one Chrome trace (``repro.obs.recorder.record_spans``).  The host sees neither when the
+    backward pass ends nor when a bucket's collective runs, so a record
+    carries no buckets and ``backward_end`` is its ``end``; per-bucket
+    device time comes from a profile of the step, read through its
+    ``bucket_<k>`` named scopes.  ``args["predicted_t_iter"]`` is the
+    closed-form prediction (``core.simulator.simulate`` over the step's
+    own plan, specs and comm model, with forward time ``t_f``).
 
     ``hlo_text`` (the compiled step's HLO, e.g. ``jax.jit(step).lower(...)
     .compile().as_text()``) attaches ``utils.hlo.analyze`` cost counters
@@ -652,10 +671,9 @@ def instrument_step(step_fn, art: StepArtifacts, *, job: str = "train",
 
     from repro.core.simulator import simulate
     from repro.obs.metrics import REGISTRY
-    from repro.obs.recorder import (BucketRecord, IterationRecord,
-                                    plan_fingerprint)
+    from repro.obs.recorder import IterationRecord, plan_fingerprint
 
-    est = simulate(art.specs, art.plan, art.comm_model, t_f)
+    predicted = simulate(art.specs, art.plan, art.comm_model, t_f).t_iter
     fingerprint = plan_fingerprint(art.plan)
     hlo_cost = None
     if hlo_text is not None:
@@ -675,25 +693,12 @@ def instrument_step(step_fn, art: StepArtifacts, *, job: str = "train",
         t1 = now()
         hist.observe(t1 - t0, job=job)
         if recorder is not None or on_record is not None:
-            # map the closed-form timeline (backward-origin clock, total
-            # span est.t_iter) onto the measured wall window [t0, t1]
-            scale = (t1 - t0) / est.t_iter if est.t_iter > 0 else 0.0
-            buckets = tuple(
-                BucketRecord(bucket=e.bucket, nbytes=e.nbytes,
-                             ready=t0 + (t_f + e.ready) * scale,
-                             start=t0 + (t_f + e.start) * scale,
-                             end=t0 + (t_f + e.end) * scale)
-                for e in est.events)
-            args = {"plan": fingerprint, "estimated_buckets": True,
-                    "predicted_t_iter": est.t_iter,
-                    "overlap_ratio": est.overlap_ratio}
+            args = {"plan": fingerprint, "predicted_t_iter": predicted}
             if step_idx == 0 and hlo_cost is not None:
                 args["hlo_cost"] = hlo_cost
             rec = IterationRecord(
                 source=source, job=job, iteration=step_idx,
-                start=t0, end=t1,
-                backward_end=t0 + (t_f + est.t_b_total) * scale,
-                buckets=buckets, args=args)
+                start=t0, end=t1, backward_end=t1, args=args)
             if recorder is not None:
                 recorder.record(rec)
             if on_record is not None:

@@ -57,6 +57,16 @@ def dag_tasks(draw):
 
 
 @hypothesis.given(dag_tasks())
+# zero-length link tasks finish inside their dispatch: one readied a task
+# twice in the first dispatch loop, one completed the graph twice
+@hypothesis.example(tasks=(
+    DAGTask("t0", 0.0, None, "l0", ()), DAGTask("t1", 0.0, "w0", None, ()),
+    DAGTask("t2", 0.0078125, "w0", None, ("t0",)),
+    DAGTask("t3", 0.0, None, None, ("t2",))))
+@hypothesis.example(tasks=(
+    DAGTask("t0", 0.0078125, None, None, ()),
+    DAGTask("t1", 0.0, "w0", None, ()),
+    DAGTask("t2", 0.0, None, "l0", ("t0",))))
 @hypothesis.settings(max_examples=60, deadline=None)
 def test_random_dag_schedules_never_deadlock(tasks):
     job = JobSpec(name="dag", specs=[], plan=make_plan("wfbp", []),

@@ -9,6 +9,7 @@ this one file so that one test worker loads the TPU compiler.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,7 +74,10 @@ def test_flash_attention_compiles_at_4096_gqa(one_chip):
 @pytest.mark.parametrize("pack_kernel", [False, True])
 def test_qwen2_step_compiles_and_fits_one_chip(topo, monkeypatch,
                                                pack_kernel):
-    """The one-chip step of chip_smoke.py at published widths, 2 layers."""
+    """The one-chip step of chip_smoke.py at published widths, 2 layers.
+    Every matmul of it lies in the step's differentiated ``forward`` scope:
+    forward (``jvp(forward)``), backward or recomputed
+    (``transpose(jvp(forward))``), so a profile attributes it to a phase."""
     from repro.configs.base import ShapeConfig
     from repro.launch.mesh import make_mesh
     from repro.models import registry
@@ -105,4 +109,9 @@ def test_qwen2_step_compiles_and_fits_one_chip(topo, monkeypatch,
         compiled = jax.jit(step_fn, donate_argnums=0).lower(
             state, batch).compile()
     assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
-    assert ("tpu_custom_call" in compiled.as_text()) == pack_kernel
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == pack_kernel
+    dots = re.findall(r"^.* (?:dot|convolution)\(.*$", text, re.M)
+    outside = [d for d in dots
+               if not re.search(r'op_name="[^"]*jvp\(forward\)', d)]
+    assert len(dots) >= 10 and not outside, outside[:3]
