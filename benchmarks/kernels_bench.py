@@ -10,8 +10,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.rmsnorm import ops as rn_ops, ref as rn_ref
+from repro.models import layers
 
 
 def _time(fn, *args, reps=3):
@@ -28,7 +28,7 @@ def run() -> list[tuple[str, float, str]]:
     q = jax.random.normal(jax.random.PRNGKey(0), (b, s, hq, d))
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, hkv, d))
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, hkv, d))
-    t_ref = _time(lambda *a: fa_ref.attention_ref(*a), q, k, v)
+    t_ref = _time(lambda *a: layers.attention_ref(*a), q, k, v)
     vmem = (128 * d + 2 * 128 * d + 128 * d) * 4 / 1024
     rows.append(("kernels.flash_attention.ref_us", t_ref * 1e6,
                  f"tile VMEM={vmem:.0f}KB/step blocks=128x128 "

@@ -6,14 +6,20 @@ function is jit/vjp-safe.  Tensor-parallel sharding is expressed with
 when no mesh with that axis is active, so single-device smoke tests run the
 identical code).
 
-The attention core is a chunked online-softmax (flash-attention schedule in
-pure ``lax.scan`` form) so 32k-524k sequence dry-runs lower without
-materializing S×S score matrices; the Pallas kernel in
-``repro/kernels/flash_attention`` implements the same schedule with explicit
-VMEM tiling for the TPU target and is validated against
-:func:`attention_ref`.  Both run under the named scope ``attention``, so
-that a profile can tell the attention core's device time from the
-projections around it.
+The attention core has two paths, chosen from what the call can observe.
+On the TPU, self-attention (causal, windowed or full) over whole 128-row
+blocks with a head dimension that is a multiple of 128 (training and
+prefill) runs the Pallas flash-attention kernels of ``repro/kernels/flash_attention``
+through their custom VJP: the score blocks stay in VMEM, fully masked
+blocks are skipped, and the backward pass keeps only q, k, v, o and a
+log-sum-exp per row.  Everything else (decode, cross-attention, heads of
+64 or 96, lengths such as whisper's 1,500 frames, steps that leave a mesh
+axis to GSPMD, and every call off the TPU) takes a chunked online softmax
+in ``lax.scan`` form, which lowers at 32k-524k without materializing S×S
+scores.  Both agree with
+:func:`attention_ref` (tested) and run under the named scope
+``attention``, so that a profile can tell the attention core's device
+time from the projections around it.
 """
 
 from __future__ import annotations
@@ -24,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.flash_attention import ops as flash_ops
 
 NEG_INF = -1e30
 
@@ -166,16 +174,36 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     return out.reshape(b, sq, hq, d).astype(q.dtype)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _takes_kernel(q, k, q_offset, kv_len, scale) -> bool:
+    """Whether :func:`attention` runs the flash-attention kernels: on the
+    TPU, self-attention from position 0 over whole 128-row blocks with a
+    head dimension of a multiple of 128, and no mesh axis left to GSPMD
+    (a Pallas call cannot be partitioned automatically)."""
+    sq, d = q.shape[1], q.shape[3]
+    return (_on_tpu() and kv_len is None and scale is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and sq == k.shape[1] and sq % 128 == 0 and d % 128 == 0
+            and not _active_mesh_axis_names())
+
+
 @jax.named_scope("attention")
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               q_offset=0, kv_len: Optional[jax.Array] = None,
               chunk: int = 1024, scale: Optional[float] = None) -> jax.Array:
-    """Memory-efficient attention: online softmax over KV chunks.
+    """Memory-efficient attention: the flash-attention kernels where
+    :func:`_takes_kernel`, else an online softmax over KV chunks of
+    ``chunk`` keys.
 
-    Never materializes more than [B, Sq, H, chunk] of scores; exact same
-    result as :func:`attention_ref` (tested).  This is the form the Pallas
-    flash kernel implements with VMEM tiles on the TPU target.
+    Neither materializes more than [B, Sq, H, chunk] of scores; both give
+    the result of :func:`attention_ref` (tested).
     """
+    if _takes_kernel(q, k, q_offset, kv_len, scale):
+        return flash_ops.flash_attention(q, k, v, causal=causal,
+                                         window=window)
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     if skv <= chunk:

@@ -11,8 +11,9 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref  # noqa: E402
+from repro.kernels.flash_attention import ops as fa_ops  # noqa: E402
 from repro.kernels.rmsnorm import ops as rn_ops  # noqa: E402
+from repro.models import layers  # noqa: E402
 
 
 @hypothesis.given(
@@ -27,7 +28,7 @@ def test_flash_attention_property(b, s, g, d, causal):
     v = jax.random.normal(jax.random.PRNGKey(5), (b, s, hkv, d))
     o = fa_ops.flash_attention(q, k, v, causal=causal, block_q=32,
                                block_k=32, interpret=True)
-    r = fa_ref.attention_ref(q, k, v, causal=causal)
+    r = layers.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=3e-5,
                                atol=3e-5)
 

@@ -71,21 +71,45 @@ def test_flash_attention_compiles_at_4096_gqa(one_chip):
         q, kv, kv)
 
 
+def test_flash_attention_grad_compiles_at_4096_gqa(one_chip):
+    """The backward kernels at qwen2's attention shape, float32 as the
+    benchmark's configuration has it: the forward, dQ and dK/dV kernels."""
+    q = jax.ShapeDtypeStruct((1, 4096, 12, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 2, 128), jnp.float32,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa_ops.flash_attention(q, k, v, interpret=False))
+
+    text = _compiles_kernel(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert re.search(rf"%{kernel}[.\d]* = .* custom-call\(", text), kernel
+
+
 @pytest.mark.parametrize("pack_kernel", [False, True])
 def test_qwen2_step_compiles_and_fits_one_chip(topo, monkeypatch,
                                                pack_kernel):
     """The one-chip step of chip_smoke.py at published widths, 2 layers.
     Every matmul of it lies in the step's differentiated ``forward`` scope:
     forward (``jvp(forward)``), backward or recomputed
-    (``transpose(jvp(forward))``), so a profile attributes it to a phase."""
+    (``transpose(jvp(forward))``), so a profile attributes it to a phase.
+    The attention core runs the flash-attention kernels, under
+    ``attention``, and their backward kernels are backward or recompute
+    to the benchmark's attribution."""
+    from benchmarks.chip import scopes
     from repro.configs.base import ShapeConfig
     from repro.launch.mesh import make_mesh
-    from repro.models import registry
+    from repro.models import layers, registry
     from repro.models.transformer import LM
     from repro.train.step import build_train_step
 
-    # the CPU backend would pick interpret mode; compile the real kernel
+    # the CPU backend would pick interpret mode and the attention scan;
+    # compile the real kernels, as on the chip
     monkeypatch.setattr(bp_ops, "_auto_interpret", lambda: False)
+    monkeypatch.setattr(fa_ops, "_auto_interpret", lambda: False)
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
     bundle = registry.get_arch("qwen2-1.5b")
     cfg = dataclasses.replace(bundle.cfg, num_layers=2)
     par = dataclasses.replace(bundle.parallel, dp_axes=("data",),
@@ -110,7 +134,19 @@ def test_qwen2_step_compiles_and_fits_one_chip(topo, monkeypatch,
             state, batch).compile()
     assert compiled.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == pack_kernel
+    kernels = {n: p for n, p in scopes.op_names(text).items()
+               if re.match(r"flash_attention_\w+\.\d+$", n)}
+    assert {n.split(".")[0] for n in kernels} == {
+        "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"}
+    for name, path in kernels.items():
+        assert path.endswith("/pallas_call") and "attention" in \
+            scopes.scope_names(path), (name, path)
+        cls = scopes.classify(path)
+        assert cls in (("forward", "recompute") if "_fwd" in name
+                       else ("backward", "recompute")), (name, cls)
+    packs = [c for c in re.findall(r"^.*tpu_custom_call.*$", text, re.M)
+             if "flash_attention" not in c]
+    assert bool(packs) == pack_kernel
     dots = re.findall(r"^.* (?:dot|convolution)\(.*$", text, re.M)
     outside = [d for d in dots
                if not re.search(r'op_name="[^"]*jvp\(forward\)', d)]
