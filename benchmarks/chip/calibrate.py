@@ -9,10 +9,10 @@ For every seed: the program's first steps through the timed entry (the
 step is compiled once), then the plain reference, and the numbers that
 ``check.py`` compares.  For each control seed the reference again with
 its parameters and every matrix product one precision below the
-configuration's (``dense_decoder.control``); for each planted fault (``faults.py``) the
-program rebuilt with the fault, on the fault seeds.  Each reading is one
-JSON line.  Runs on the chip only: the limits are set from these
-readings at the cell's own size.
+configuration's (the reference's ``control``); for each planted fault
+(``faults.py``) the program rebuilt with the fault, on the fault seeds.
+Each reading is one JSON line.  Runs on the chip only: the limits are set
+from these readings at the cell's own size.
 """
 
 import time
@@ -41,7 +41,6 @@ def main(argv=None) -> int:
 
     import jax
     from benchmarks.chip import faults, feed, harness, manifest
-    from benchmarks.chip.reference import dense_decoder
     from repro.launch.compile_cache import enable_compile_cache
 
     devices = jax.devices()
@@ -99,7 +98,8 @@ def main(argv=None) -> int:
               "loss": prog["loss"], "ref_loss": refs[seed]["loss"],
               "steps_s": dt, "reference_s": time.perf_counter() - t,
               "peak_bytes": peak})
-    low = dense_decoder.LOWER[cell.config["model"]["dtype"]]
+    low = manifest.reference(cell.config).LOWER[
+        cell.config["model"]["dtype"]]
     for seed in args.control_seeds:
         t = time.perf_counter()
         ctl = harness.reference_readings(b, seed, control=True)

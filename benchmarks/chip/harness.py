@@ -51,12 +51,30 @@ class Built:
     state_sh: object
     batch_sh: object
     shapes: object         # the parameter tree's ShapeDtypeStructs
+    opt_shapes: list       # the optimizer state's, one item per group
     ents: list             # weights.entries of the parameter tree
     rows: int              # sequences in the global batch
 
     @property
     def tokens_per_step(self) -> int:
         return self.rows * self.cell.traffic["seq_len"]
+
+
+def with_settings(obj, settings: dict):
+    """The dataclass ``obj`` with the fields that ``settings`` names
+    replaced.  An object under a field that holds a dataclass (``moe``,
+    ``mamba``) replaces that dataclass's own fields, so that a file can set
+    ``{"moe": {"num_experts": 8}}`` and keep the rest of the group."""
+    changes = {}
+    for key, value in settings.items():
+        if isinstance(value, dict):
+            group = getattr(obj, key)
+            if not dataclasses.is_dataclass(group):
+                raise ValueError(f"{key}: {type(obj).__name__} holds no "
+                                 f"group of settings there to change")
+            value = with_settings(group, value)
+        changes[key] = value
+    return dataclasses.replace(obj, **changes)
 
 
 def build(cell: manifest.Cell, devices) -> Built:
@@ -71,8 +89,8 @@ def build(cell: manifest.Cell, devices) -> Built:
     conf, traffic = cell.config, cell.traffic
     opt = traffic["optimizer"]
     bundle = registry.get_arch(conf["arch"])
-    cfg = dataclasses.replace(bundle.cfg, **conf["model"])
-    par = dataclasses.replace(bundle.parallel, **conf["parallel"])
+    cfg = with_settings(bundle.cfg, conf["model"])
+    par = with_settings(bundle.parallel, conf["parallel"])
     rows = len(devices) * traffic["batch_per_chip"]
     shape = ShapeConfig(cell.traffic_name, "train", traffic["seq_len"], rows)
     run = dataclasses.replace(
@@ -83,15 +101,16 @@ def build(cell: manifest.Cell, devices) -> Built:
         adam_b1=opt["b1"], adam_b2=opt["b2"], adam_eps=opt["eps"],
         grad_clip=opt["grad_clip"])
     mesh = make_mesh_for(len(devices), par.tp_enabled)
-    step_fn, init_fn, art = train_step.build_train_step(LM(cfg, par), run,
-                                                        mesh)
+    model = LM(cfg, par)
+    step_fn, init_fn, art = train_step.build_train_step(model, run, mesh)
     is_p = lambda x: isinstance(x, P)
     state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                             art.state_pspecs, is_leaf=is_p)
-    shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0)).params
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
     return Built(cell, cfg, run, mesh, step_fn, init_fn, art, state_sh,
-                 NamedSharding(mesh, art.batch_pspec), shapes,
-                 weights.entries(shapes, cfg.num_layers), rows)
+                 NamedSharding(mesh, art.batch_pspec), state.params,
+                 state.opt_state,
+                 weights.entries(state.params, model.stage_layout()), rows)
 
 
 def describe(b: Built) -> None:
@@ -99,7 +118,8 @@ def describe(b: Built) -> None:
     log(f"cell {b.cell.name}: {c.name} layers={c.num_layers} "
         f"d_model={c.d_model} heads={c.num_heads}/{c.num_kv_heads}x"
         f"{c.resolved_head_dim} d_ff={c.d_ff} vocab={c.vocab_size} "
-        f"tied={c.tie_embeddings} qkv_bias={c.qkv_bias} dtype={c.dtype}")
+        f"tied={c.tie_embeddings} qkv_bias={c.qkv_bias} dtype={c.dtype}"
+        + (f" moe={c.moe}" if c.moe else ""))
     log(f"job: {b.rows} x {b.cell.traffic['seq_len']} tokens, microbatch "
         f"{b.run.microbatch}, mesh {dict(b.mesh.shape)}, zero={p.zero} "
         f"strategy={p.comm_strategy} pack_kernel={p.pack_kernel}")
@@ -145,7 +165,12 @@ def first_grad_fn(b: Built):
     """Per entry, the squared norm of the first gradient as the optimizer
     got it, worked out from its state after one step: AdamW's first moment
     is then (1 - b1) g.  Under ZeRO-1 the moments of each bucket of the
-    merge plan lie back to back in one flat buffer, leaves in plan order."""
+    merge plan lie back to back in one flat buffer, leaves in plan order.
+    Under expert parallelism the expert leaves are in no bucket: their
+    group's state comes last, a tree shaped like the parameters with
+    ``{"m", "v"}`` at each expert leaf."""
+    if b.run.parallel.zero != 1:
+        raise ValueError("the first-gradient reading is written for ZeRO-1")
     flat = {jax.tree_util.keystr(p): s for p, s in
             jax.tree_util.tree_flatten_with_path(b.shapes)[0]}
     where = {}
@@ -156,24 +181,39 @@ def first_grad_fn(b: Built):
             size = math.prod(flat[name].shape)
             where[name] = (k, off, size)
             off += size
-    if set(where) != set(flat):
-        raise ValueError("the merge plan does not cover every parameter")
+    has_experts = len(b.opt_shapes) > len(b.art.plan.buckets)
+    experts = set(_first_moments(b.opt_shapes[-1])) if has_experts else set()
+    if where.keys() & experts or where.keys() | experts != flat.keys():
+        raise ValueError("the merge plan and the expert group do not cover "
+                         "every parameter once")
     b1 = b.cell.traffic["optimizer"]["b1"]
 
     def sq(opt_state):
+        ep_m = _first_moments(opt_state[-1]) if experts else {}
         out = []
-        for _, name, layer in b.ents:
-            k, off, size = where[name]
-            if layer is not None:
-                per = size // flat[name].shape[0]
-                off, size = off + layer * per, per
-            seg = opt_state[k]["m"][off:off + size]
+        for _, name, rep in b.ents:
+            if name in ep_m:
+                seg = ep_m[name] if rep is None else ep_m[name][rep]
+            else:
+                k, off, size = where[name]
+                if rep is not None:
+                    per = size // flat[name].shape[0]
+                    off, size = off + rep * per, per
+                seg = opt_state[k]["m"][off:off + size]
             out.append(jnp.sum(jnp.square(seg.astype(jnp.float32))))
         return jnp.stack(out) / (1 - b1) ** 2
 
-    if b.run.parallel.zero != 1:
-        raise ValueError("the first-gradient reading is written for ZeRO-1")
     return jax.jit(sq)
+
+
+def _first_moments(group_state) -> dict:
+    """{parameter path: its first moment} of a state tree that holds
+    ``{"m", "v"}`` at each leaf of the parameters it covers."""
+    out = {}
+    for path, x in jax.tree_util.tree_flatten_with_path(group_state)[0]:
+        if getattr(path[-1], "key", None) == "m":
+            out[jax.tree_util.keystr(path[:-1])] = x
+    return out
 
 
 class Stepper:
@@ -355,17 +395,22 @@ def peaks_of(kind: str) -> dict:
     return table[kind]
 
 
+def flops_per_token(b: Built) -> int | float:
+    """Model FLOPs per training token of the configuration as the program
+    resolved it, the router spanning the published experts."""
+    return flops.train_flops_per_token(dataclasses.asdict(b.cfg),
+                                       b.cell.traffic["seq_len"],
+                                       b.cell.config.get("published", {}))
+
+
 def per_layer(b: Built, tz: trace.Trace, spans, steps: int, elapsed: float,
               peak: dict, chips: int):
     """(per-layer metrics, device busy/window seconds, breakdown) of a
     traced window."""
     w = tz.span("window")
     lo, hi = w.start, w.end
-    model = b.cell.config["model"]
     ctx = Context(b.cell, tz, lo, hi, spans, steps, elapsed,
-                  b.tokens_per_step,
-                  flops.train_flops_per_token(model, b.cell.traffic["seq_len"]),
-                  chips, peak)
+                  b.tokens_per_step, flops_per_token(b), chips, peak)
     metrics = {}
     for m in b.cell.per_layer:
         v = manifest.metric_reader(m["name"])(ctx)

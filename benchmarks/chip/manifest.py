@@ -10,6 +10,19 @@ or a metric by adding files and entries only:
 * a cell's limits for the comparison: ``limits/<cell>.json``;
 * a per-layer metric: ``metrics/<name>.py``, whose ``read(ctx)`` returns a
   number, or None where it finds nothing to read.
+
+A configuration file names the program's architecture (``arch``) and
+changes its settings: ``model`` the ``ModelConfig``'s, ``parallel`` the
+``ParallelConfig``'s.  An object under a key that holds a group of
+settings (``moe``, ``mamba``) changes that group's fields and keeps the
+rest (``harness.with_settings``).  Every key changed from the published
+model is a cut, named by a dotted key (``num_layers``, ``moe.num_experts``)
+in the file's ``reduced`` (why) and ``published`` (the published value),
+and in the ``configs`` entry's ``reduced``.  A chip's share of a
+deployment is written as such cuts: ``model.moe.num_experts`` is the
+number of experts held here and ``published["moe.num_experts"]`` the
+number the router spans; a sliced vocabulary is a smaller
+``vocab_size``.  Widths are never cut.
 """
 
 from __future__ import annotations

@@ -49,19 +49,24 @@ def make_params(shapes, key):
         treedef, [_leaf(p, s, k) for (p, s), k in zip(flat, keys)])
 
 
-def entries(shapes, num_layers: int) -> list[tuple[str, str, int | None]]:
-    """(entry name, leaf path, layer) in tree order.  A leaf stacked over
-    the layers (a scanned stage's leading axis) gives one entry per layer,
-    so that a fault in one layer is not averaged over the others."""
+def entries(shapes, stages) -> list[tuple[str, str, int | None]]:
+    """(entry name, leaf path, index on the leaf's leading axis) in tree
+    order.  ``stages`` is the program's stage layout, one item per entry of
+    ``shapes["stages"]`` with its ``first_layer``, ``period`` and
+    ``repeats``.  A scanned stage stacks each of its blocks' leaves over
+    the repeats on a leading axis; such a leaf gives one entry per repeat,
+    named by its global layer index, so that a fault in one layer is not
+    averaged over the others."""
     out = []
-    for path, s in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+    for path, _ in jax.tree_util.tree_flatten_with_path(shapes)[0]:
         k = jax.tree_util.keystr(path)
-        stacked = (k.startswith("['stages']") and len(s.shape) >= 2
-                   and s.shape[0] == num_layers and num_layers > 1)
-        if stacked:
-            out += [(f"{k}#{l}", k, l) for l in range(num_layers)]
-        else:
+        st = stages[path[1].idx] if _name(path[:1]) == "stages" else None
+        if st is None or st.repeats <= 1:
             out.append((k, k, None))
+            continue
+        block = int(_name(path[:3]).removeprefix("blk"))
+        out += [(f"{k}#{st.first_layer + r * st.period + block}", k, r)
+                for r in range(st.repeats)]
     return out
 
 
@@ -70,8 +75,8 @@ def entry_sq_norms(tree, ents) -> jax.Array:
     by_path = {jax.tree_util.keystr(p): v for p, v in
                jax.tree_util.tree_flatten_with_path(tree)[0]}
     out = []
-    for _, k, layer in ents:
-        x = by_path[k] if layer is None else by_path[k][layer]
+    for _, k, rep in ents:
+        x = by_path[k] if rep is None else by_path[k][rep]
         out.append(jnp.sum(jnp.square(x.astype(jnp.float32))))
     return jnp.stack(out)
 
